@@ -18,7 +18,7 @@ import sys
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import (
         depth_morph,
         dse_speed,
@@ -32,8 +32,10 @@ def main() -> None:
         width_morph,
     )
 
+    from repro.compile_cache import enable_compile_cache
     from repro.kernels.morph_matmul import trace_count
 
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else ""
     suites = {
         "pareto_front": pareto_front.run,
@@ -47,6 +49,10 @@ def main() -> None:
         "roofline_report": roofline_report.run,
         "serve_continuous": serve_continuous.run,
     }
+    if only and only not in suites:
+        print(f"unknown suite {only!r}; choose from {sorted(suites)}")
+        return 2
+    failed = []
     for name, fn in suites.items():
         if only and name != only:
             continue
@@ -54,14 +60,19 @@ def main() -> None:
         t0 = trace_count()
         try:
             fn()
-        except Exception:  # noqa: BLE001 — a failing suite must not kill the run
+        except Exception:  # noqa: BLE001 — run the rest, then fail the run
             print(f"{name}/SUITE_ERROR,0.0,{{}}")
             traceback.print_exc()
+            failed.append(name)
         # single-executable accounting: morph kernel compiles this suite
         # triggered (width sweeps should add shapes, never widths)
         print(f"# {name}: morph_matmul_compiles={trace_count() - t0}",
               flush=True)
+    if failed:
+        print(f"# failed suites: {', '.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

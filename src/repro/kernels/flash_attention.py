@@ -77,7 +77,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     group: int = 1, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 128, interpret: bool = True) -> jnp.ndarray:
+                    bq: int = 128, bk: int = 128, interpret: bool = False) -> jnp.ndarray:
     """q: (BH, Sq, hd); k, v: (BKV, Sk, hd) with BH == BKV * group."""
     BH, Sq, hd = q.shape
     BKV, Sk, _ = k.shape

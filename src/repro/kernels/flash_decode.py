@@ -71,7 +71,7 @@ def _kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  kv_len, k_scale: Optional[jnp.ndarray] = None,
                  v_scale: Optional[jnp.ndarray] = None, *, group: int = 1,
-                 bk: int = 128, interpret: bool = True) -> jnp.ndarray:
+                 bk: int = 128, interpret: bool = False) -> jnp.ndarray:
     """q: (BH, hd); k/v: (BKV, S, hd); scales (BKV, S, 1) iff int8 cache.
 
     ``kv_len`` is a dynamic scalar: positions >= kv_len are masked and whole

@@ -37,9 +37,15 @@ Layout contract: the kernel always consumes the cache as a *page pool*
 ``(n_pages, page_size, KV, hd)`` plus a per-slot ``(B, P)`` int32 table.
 Dense caches are normalized to this layout with an identity table (a free
 reshape), so a single kernel body serves both the dense and the block-paged
-cache. Garbage / unwritten / stale columns are excluded via the absolute
-``kpos`` operand exactly like the unfused path (masked columns contribute
-exact zeros).
+cache. Garbage / unwritten / stale columns are excluded by absolute key
+positions the kernel derives from each slot's scalar-prefetched position,
+with the unfused path's rule (masked columns contribute exact zeros).
+
+Mosaic layout rules shape the kernel bodies: per-head rows are built with
+lane slices and sublane concatenation (a reshape that splits the lane dim
+is refused), and every block is either (8, 128)-aligned or a whole array
+dimension. ``tests/test_tpu_compile.py`` compiles both kernels for a
+described TPU v5e.
 
 ``trace_count()`` counts wrapper traces under an enclosing ``jax.jit`` —
 the zero-re-trace tests measure the single-executable claim with it. (The
@@ -363,20 +369,113 @@ def _as_pool(cache, pages, page_size, B):
 
 
 def _rope_rows(x, positions, theta: float):
-    """In-kernel RoPE. x: (..., hd) f32; positions broadcastable to x[..., :1]."""
+    """In-kernel RoPE. x: (R, hd) f32; positions: (R, 1) or scalar f32."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
-                    * (math.log(theta) / half))
+    freqs = jnp.exp(-jax.lax.broadcasted_iota(jnp.int32, (1, half), 1)
+                    .astype(jnp.float32) * (math.log(theta) / half))
     ang = positions * freqs  # broadcast
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = x[:, :half], x[:, half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _heads_to_rows(x, n: int, hd: int):
+    """(R, n*hd) -> (n*R, hd), head-major rows (row h*R + r).
+
+    Lane slices plus a sublane concat: Mosaic refuses a reshape that splits
+    the lane dimension, and head_dim is often below the 128-lane width.
+    """
+    return jnp.concatenate([x[:, h * hd:(h + 1) * hd] for h in range(n)],
+                           axis=0)
+
+
+def _rows_to_heads(x, n: int, R: int):
+    """Inverse of ``_heads_to_rows``: (n*R, hd) -> (R, n*hd)."""
+    return jnp.concatenate([x[h * R:(h + 1) * R, :] for h in range(n)],
+                           axis=1)
+
+
+def _proj(x, w_ref, active):
+    """Active-width projection (morph_matmul's column gate), f32 result."""
+    y = jax.lax.dot_general(x, w_ref[...], (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    return jnp.where(cols < active, y, 0.0)
+
+
+def _decode_valid(p, ik, bk: int, S: int, window: int):
+    """(1, 1, bk) mask of live cache columns in KV tile ``ik`` for a slot at
+    absolute position ``p`` (a scalar), derived in-kernel from ``p`` alone.
+
+    Same rule as the unfused path: column ``c`` holds absolute position
+    ``kpos(c)`` (rolling buffer under a sliding window), live when
+    ``0 <= kpos <= p`` and inside the window; the slot being written this
+    step is excluded (stale until the write-back; the kernel's in-register
+    extension column stands in for it).
+    """
+    col = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+    if window:
+        slot = jax.lax.rem(p, S)
+        wraps = jnp.where(col <= slot, 0, 1)
+        kp = (jax.lax.div(p, S) - wraps) * S + col
+        valid = (kp >= 0) & (kp <= p) & (kp > p - window)
+    else:
+        slot = jnp.minimum(p, S - 1)
+        valid = col <= p
+    return valid & (col != slot)
+
+
+def _verify_kpos(p, ik, bk: int, S: int, window: int):
+    """(1, 1, bk) absolute positions of the committed cache columns in tile
+    ``ik`` before a verify pass at ``p`` (``_cache_kpos`` in-kernel); a
+    negative value marks a column that holds no position."""
+    col = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+    if window:
+        # p == 0 has no live tile (lens is 0), so clamping keeps the scalar
+        # division non-negative without changing any live column
+        last = jnp.maximum(p - 1, 0)
+        wraps = jnp.where(col <= jax.lax.rem(last, S), 0, 1)
+        return (jax.lax.div(last, S) - wraps) * S + col
+    return jnp.where(col < p, col, -1)
+
+
+def _online_softmax_update(s, valid, v, m_s, l_s, acc_s, KV: int, R: int,
+                           hd: int):
+    """Flash-attention accumulator update for one KV tile.
+
+    s: (KV, R, bk) scores; valid broadcastable to s; v: (KV, bk, hd). The
+    running max / sum / accumulator live in (KV*R, 1|hd) scratch rows.
+    Explicit zeroing keeps fully-masked tiles exact (m_new can sit at
+    KERNEL_NEG_INF, where exp(s - m_new) would be 1, not 0).
+    """
+    s = jnp.where(valid, s, KERNEL_NEG_INF)
+    m_prev = m_s[...].reshape(KV, R, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_s[...] = (l_s[...].reshape(KV, R, 1) * alpha
+                + jnp.sum(pexp, axis=-1, keepdims=True)).reshape(KV * R, 1)
+    pv = jax.lax.dot_general(pexp, v, (((2,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+    acc_s[...] = (acc_s[...].reshape(KV, R, hd) * alpha
+                  + pv).reshape(KV * R, hd)
+    m_s[...] = m_new.reshape(KV * R, 1)
+
+
+def _load_kv_tile(k_ref, ks_ref, v_ref, vs_ref, quant: bool):
+    """One cache tile as head-major f32 (KV, bk, hd) K and V."""
+    k = k_ref[0].astype(jnp.float32)  # (bk, KV, hd)
+    v = v_ref[0].astype(jnp.float32)
+    if quant:
+        k = k * ks_ref[0].astype(jnp.float32)
+        v = v * vs_ref[0].astype(jnp.float32)
+    return k.transpose(1, 0, 2), v.transpose(1, 0, 2)
 
 
 def _decode_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
                    x_ref, wq_ref, wk_ref, wv_ref, wo_ref,
-                   k_ref, ks_ref, v_ref, vs_ref, kpos_ref,
+                   k_ref, ks_ref, v_ref, vs_ref,
                    o_ref, kn_ref, vn_ref, kns_ref, vns_ref,
                    q_s, ke_s, ve_s, m_s, l_s, acc_s,
                    *, bk, nk, H, KV, hd, scale, window, quant, use_rope,
@@ -389,24 +488,12 @@ def _decode_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
     akv = akv_ref[b]
 
     @pl.when(ik == 0)
-    def _proj():
-        xf = x_ref[0].astype(jnp.float32)  # (1, dm)
+    def _project():
+        x = x_ref[0]  # (1, dm), weights' dtype
         pf = p.astype(jnp.float32)
-        # fused active-width QKV projection (morph_matmul's column gate)
-        q = jax.lax.dot_general(xf, wq_ref[...].astype(jnp.float32),
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        qcols = jax.lax.broadcasted_iota(jnp.int32, (1, H * hd), 1)
-        q = jnp.where(qcols < aq, q, 0.0).reshape(H, hd)
-        kv_cols = jax.lax.broadcasted_iota(jnp.int32, (1, KV * hd), 1)
-        kn = jax.lax.dot_general(xf, wk_ref[...].astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        kn = jnp.where(kv_cols < akv, kn, 0.0).reshape(KV, hd)
-        vn = jax.lax.dot_general(xf, wv_ref[...].astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        vn = jnp.where(kv_cols < akv, vn, 0.0).reshape(KV, hd)
+        q = _heads_to_rows(_proj(x, wq_ref, aq), H, hd)     # (H, hd)
+        kn = _heads_to_rows(_proj(x, wk_ref, akv), KV, hd)  # (KV, hd)
+        vn = _heads_to_rows(_proj(x, wv_ref, akv), KV, hd)
         if use_rope:
             q = _rope_rows(q, pf, rope_theta)
             kn = _rope_rows(kn, pf, rope_theta)
@@ -441,58 +528,90 @@ def _decode_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
 
     @pl.when(live)
     def _tile():
-        k = k_ref[0].astype(jnp.float32)  # (bk, KV, hd)
-        v = v_ref[0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0].astype(jnp.float32)
-            v = v * vs_ref[0].astype(jnp.float32)
-        kt = k.transpose(1, 0, 2)  # (KV, bk, hd)
-        vt = v.transpose(1, 0, 2)
+        kt, vt = _load_kv_tile(k_ref, ks_ref, v_ref, vs_ref, quant)
         qg = q_s[...].reshape(KV, G, hd)
         s = jax.lax.dot_general(qg, kt, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        kp = kpos_ref[0]  # (bk,) absolute positions (slot column pre-masked)
-        valid = jnp.logical_and(kp >= 0, kp <= p)
-        if window:
-            valid = jnp.logical_and(valid, kp > p - window)
-        s = jnp.where(valid[None, None, :], s, KERNEL_NEG_INF)
-        m_prev = m_s[...].reshape(KV, G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zeroing keeps fully-masked tiles exact (m_new can sit at
-        # KERNEL_NEG_INF, where exp(s - m_new) would be 1, not 0)
-        pexp = jnp.where(valid[None, None, :], jnp.exp(s - m_new), 0.0)
-        l_s[...] = (l_s[...].reshape(KV, G, 1) * alpha
-                    + jnp.sum(pexp, axis=-1, keepdims=True)).reshape(H, 1)
-        pv = jax.lax.dot_general(pexp, vt, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        acc_s[...] = (acc_s[...].reshape(KV, G, hd) * alpha + pv).reshape(H, hd)
-        m_s[...] = m_new.reshape(H, 1)
+        valid = _decode_valid(p, ik, bk, nk * bk, window)
+        _online_softmax_update(s, valid, vt, m_s, l_s, acc_s, KV, G, hd)
 
     @pl.when(ik == nk)
     def _finish():
         # extension column: the new (round-tripped) K/V at absolute pos p —
         # always live (p <= p, inside any window)
         qg = q_s[...].reshape(KV, G, hd)
-        ke = ke_s[...]  # (KV, hd)
-        ve = ve_s[...]
-        s_e = jax.lax.dot_general(qg, ke, (((2,), (1,)), ((0,), (0,))),
-                                  preferred_element_type=jnp.float32) * scale
-        s_e = s_e[..., None]  # (KV, G, 1)
+        ke = ke_s[...][:, None, :]  # (KV, 1, hd)
+        ve = ve_s[...][:, None, :]
+        s_e = jnp.sum(qg * ke, axis=-1, keepdims=True) * scale  # (KV, G, 1)
         m_prev = m_s[...].reshape(KV, G, 1)
         m_new = jnp.maximum(m_prev, s_e)
         alpha = jnp.exp(m_prev - m_new)
         pexp = jnp.exp(s_e - m_new)
         l = l_s[...].reshape(KV, G, 1) * alpha + pexp
-        acc = acc_s[...].reshape(KV, G, hd) * alpha + pexp * ve[:, None, :]
-        out = acc / jnp.maximum(l, 1e-20)  # (KV, G, hd)
-        oh = out.reshape(1, H * hd)
-        ocols = jax.lax.broadcasted_iota(jnp.int32, (1, H * hd), 1)
-        oh = jnp.where(ocols < aq, oh, 0.0)  # wo's active_k contraction gate
-        o = jax.lax.dot_general(oh, wo_ref[...].astype(jnp.float32),
-                                (((1,), (0,)), ((), ())),
+        acc = acc_s[...].reshape(KV, G, hd) * alpha + pexp * ve
+        out = (acc / jnp.maximum(l, 1e-20)).reshape(H, hd)
+        oh = _rows_to_heads(out, H, 1).astype(wo_ref.dtype)  # (1, H*hd)
+        # wo's active_k contraction gate
+        ocols = jax.lax.broadcasted_iota(jnp.int32, oh.shape, 1)
+        oh = jnp.where(ocols < aq, oh, jnp.zeros_like(oh))
+        o = jax.lax.dot_general(oh, wo_ref[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _weight_specs(dm: int, q_dim: int, kv_dim: int, d_out: int):
+    """Whole-matrix weight blocks: constant index maps, so each weight is
+    DMA'd once per call; one buffer each, since it is never re-fetched."""
+    def spec(shape):
+        return pl.BlockSpec(shape, lambda b, ik, *s: (0, 0),
+                            pipeline_mode=pl.Buffered(1))
+    return [spec((dm, q_dim)), spec((dm, kv_dim)), spec((dm, kv_dim)),
+            spec((q_dim, d_out))]
+
+
+def _kv_specs(bk: int, KV: int, hd: int, nk: int, quant: bool):
+    """Cache tile specs through the page table. Tiles past a slot's length
+    map to its last live tile, so the pipeline issues no DMA for them."""
+    def last_live(b, lens_):
+        return jnp.maximum(lens_[b] - 1, 0) // bk
+
+    def pool_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
+        return (tbl_[b, jnp.minimum(jnp.minimum(ik, nk - 1),
+                                    last_live(b, lens_))], 0, 0, 0)
+
+    def scale_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
+        if quant:
+            return pool_map(b, ik, lens_, pos_, aq_, akv_, tbl_)
+        return (0, 0, 0, 0)
+
+    return [pl.BlockSpec((1, bk, KV, hd), pool_map),
+            pl.BlockSpec((1, bk, KV, 1), scale_map),
+            pl.BlockSpec((1, bk, KV, hd), pool_map),
+            pl.BlockSpec((1, bk, KV, 1), scale_map)]
+
+
+# Resident blocks a fused launch may hold: v5e has 128 MiB of VMEM per core,
+# and the scoped limit below adds half again plus 8 MiB for Mosaic's own
+# scratch, which stays under it.
+_VMEM_BUDGET = 72 * 2**20
+
+
+def _compiler_params(weights, x_block_bytes: int, kv_block_bytes: int,
+                     scratch_bytes: int):
+    """Scoped-VMEM limit sized from the blocks the kernel keeps resident:
+    single-buffered weights plus double-buffered activation and KV tiles,
+    with headroom for Mosaic's internal scratch. Refuses (at trace time)
+    a layer whose attention weights cannot stay resident."""
+    w_bytes = sum(int(np.prod(w.shape)) * w.dtype.itemsize for w in weights)
+    need = w_bytes + 2 * (x_block_bytes + kv_block_bytes) + scratch_bytes
+    if need > _VMEM_BUDGET:
+        raise ValueError(
+            f"fused decode keeps one layer's attention weights resident in "
+            f"VMEM: {need / 2**20:.1f} MiB exceeds the "
+            f"{_VMEM_BUDGET / 2**20:.0f} MiB budget; serve this width "
+            f"unfused (fused=False)")
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(max(need * 1.5, 16 * 2**20)) + (8 << 20))
 
 
 def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
@@ -509,24 +628,13 @@ def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
     pos_b = pos if per_slot else jnp.broadcast_to(pos, (B,))
     pool, table, bk, nk, S = _as_pool(cache, pages, page_size, B)
     slot = jnp.mod(pos_b, S) if window else jnp.minimum(pos_b, S - 1)
-
-    # absolute position of every *logical* cache column after this step's
-    # write (depends only on pos and S); the slot column itself is excluded
-    # (stale until the write) — the kernel's in-register extension stands in
-    idx = jnp.arange(S)[None, :]
-    if window:
-        wraps = jnp.where(idx <= jnp.mod(pos_b[:, None], S), 0, 1)
-        kpos = (pos_b[:, None] // S - wraps) * S + idx
-        kpos = jnp.where(kpos < 0, -10**9, kpos)
-    else:
-        kpos = jnp.where(idx <= pos_b[:, None], idx, -10**9)
-    kpos = kpos.at[jnp.arange(B), slot].set(-10**9).astype(jnp.int32)
     lens = (jnp.where(pos_b > 0, S, 0) if window
             else jnp.minimum(pos_b + 1, S)).astype(jnp.int32)
 
-    wq, wk, wv, wo = params["wq"], params["wk"], params["wv"], params["wo"]
+    # weights in the activation dtype, as the unfused projections cast them
+    weights = [params[n].astype(dt) for n in ("wq", "wk", "wv", "wo")]
     dm = x.shape[-1]
-    d_out = wo.shape[1]
+    d_out = weights[3].shape[1]
     cache_dt = pool["k"].dtype
     if quant:
         ksp, vsp = pool["k_scale"], pool["v_scale"]
@@ -534,33 +642,24 @@ def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
         ksp = jnp.zeros((1, bk, KV, 1), jnp.float32)
         vsp = ksp
 
-    def _pool_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
-        return (tbl_[b, jnp.minimum(ik, nk - 1)], 0, 0, 0)
-
-    def _scale_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
-        if quant:
-            return (tbl_[b, jnp.minimum(ik, nk - 1)], 0, 0, 0)
-        return (0, 0, 0, 0)
-
     kern = functools.partial(
         _decode_kernel, bk=bk, nk=nk, H=H, KV=KV, hd=hd,
         scale=1.0 / math.sqrt(hd), window=window, quant=quant,
         use_rope=bool(cfg.use_rope), rope_theta=cfg.rope_theta)
+    scratch = [
+        pltpu.VMEM((H, hd), jnp.float32),   # q
+        pltpu.VMEM((KV, hd), jnp.float32),  # new k (round-tripped)
+        pltpu.VMEM((KV, hd), jnp.float32),  # new v
+        pltpu.VMEM((H, 1), jnp.float32),    # running max
+        pltpu.VMEM((H, 1), jnp.float32),    # running sum
+        pltpu.VMEM((H, hd), jnp.float32),   # running acc
+    ]
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, nk + 1),
-        in_specs=[
-            pl.BlockSpec((1, 1, dm), lambda b, ik, *s: (b, 0, 0)),
-            pl.BlockSpec((dm, H * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((dm, KV * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((dm, KV * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((H * hd, d_out), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((1, bk, KV, hd), _pool_map),
-            pl.BlockSpec((1, bk, KV, 1), _scale_map),
-            pl.BlockSpec((1, bk, KV, hd), _pool_map),
-            pl.BlockSpec((1, bk, KV, 1), _scale_map),
-            pl.BlockSpec((1, bk), lambda b, ik, *s: (b, jnp.minimum(ik, nk - 1))),
-        ],
+        in_specs=[pl.BlockSpec((1, 1, dm), lambda b, ik, *s: (b, 0, 0))]
+        + _weight_specs(dm, H * hd, KV * hd, d_out)
+        + _kv_specs(bk, KV, hd, nk, quant),
         out_specs=[
             pl.BlockSpec((1, 1, d_out), lambda b, ik, *s: (b, 0, 0)),
             pl.BlockSpec((1, KV, hd), lambda b, ik, *s: (b, 0, 0)),
@@ -568,15 +667,9 @@ def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
             pl.BlockSpec((1, KV, 1), lambda b, ik, *s: (b, 0, 0)),
             pl.BlockSpec((1, KV, 1), lambda b, ik, *s: (b, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),   # q
-            pltpu.VMEM((KV, hd), jnp.float32),  # new k (round-tripped)
-            pltpu.VMEM((KV, hd), jnp.float32),  # new v
-            pltpu.VMEM((H, 1), jnp.float32),    # running max
-            pltpu.VMEM((H, 1), jnp.float32),    # running sum
-            pltpu.VMEM((H, hd), jnp.float32),   # running acc
-        ],
+        scratch_shapes=scratch,
     )
+    kv_bytes = 2 * bk * KV * (hd * cache_dt.itemsize + 128 * 4)
     out, k_new, v_new, k_sc, v_sc = pl.pallas_call(
         kern, grid_spec=gs,
         out_shape=[
@@ -586,14 +679,17 @@ def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
             jax.ShapeDtypeStruct((B, KV, 1), jnp.bfloat16),
             jax.ShapeDtypeStruct((B, KV, 1), jnp.bfloat16),
         ],
+        compiler_params=_compiler_params(
+            weights, (dm + d_out) * dt.itemsize, kv_bytes,
+            (3 * H + 2 * KV) * 128 * 4),
         interpret=interpret,
     )(lens, pos_b, jnp.asarray(a_q, jnp.int32) if a_q is not None
       else jnp.full((B,), H * hd, jnp.int32),
       jnp.asarray(a_kv, jnp.int32) if a_kv is not None
       else jnp.full((B,), KV * hd, jnp.int32),
       table.astype(jnp.int32),
-      x, wq, wk, wv, wo,
-      pool["k"], ksp, pool["v"], vsp, kpos)
+      x, *weights,
+      pool["k"], ksp, pool["v"], vsp)
 
     # cache write-back (same formulas as the unfused path)
     new_cache = dict(cache)
@@ -620,50 +716,42 @@ def _decode_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, pages, page_size,
 
 def _verify_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
                    x_ref, wq_ref, wk_ref, wv_ref, wo_ref,
-                   k_ref, ks_ref, v_ref, vs_ref, kpos_ref, offs_ref, ext_ref,
+                   k_ref, ks_ref, v_ref, vs_ref, qoff_ref, ext_ref,
                    o_ref, kn_ref, vn_ref,
                    q_s, ke_s, ve_s, m_s, l_s, acc_s,
                    *, bk, nkc, S, H, KV, hd, scale, window, quant, use_rope,
                    rope_theta):
-    """Verify/tree-verify superkernel. ``offs_ref`` (1, S) node depths and
-    ``ext_ref`` (S, S) ancestor mask are batch-constant operands built from
-    STATIC numpy in the wrapper — under the serving jit they are trace-time
-    constants embedded in the executable (one executable per topology),
-    replacing the unfused path's dense (B, S, cache+S) additive bias."""
+    """Verify/tree-verify superkernel over S query positions.
+
+    Rows of the per-head state are head-major (``h*S + s``). ``qoff_ref``
+    (H*S, 1) holds each row's node depth and ``ext_ref`` (G*S, S) the
+    row-to-new-column mask; both are built from STATIC numpy in the wrapper,
+    so under the serving jit they are constants of the executable (one
+    executable per topology), replacing the unfused path's dense
+    (B, S, cache+S) additive bias."""
     b = pl.program_id(0)
     ik = pl.program_id(1)
     G = H // KV
     p = pos_ref[b]
     aq = aq_ref[b]
     akv = akv_ref[b]
-    offs_c = offs_ref[0]                       # (S,) int32
-    row_offs = jnp.tile(offs_c, (G,))          # (G*S,)
 
     @pl.when(ik == 0)
-    def _proj():
-        xf = x_ref[0].astype(jnp.float32)  # (S, dm)
-        qpos = (p + offs_c).astype(jnp.float32)[:, None, None]  # (S,1,1)
-        q = jax.lax.dot_general(xf, wq_ref[...].astype(jnp.float32),
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        qcols = jax.lax.broadcasted_iota(jnp.int32, (1, H * hd), 1)
-        q = jnp.where(qcols < aq, q, 0.0).reshape(S, H, hd)
-        kv_cols = jax.lax.broadcasted_iota(jnp.int32, (1, KV * hd), 1)
-        kn = jax.lax.dot_general(xf, wk_ref[...].astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        kn = jnp.where(kv_cols < akv, kn, 0.0).reshape(S, KV, hd)
-        vn = jax.lax.dot_general(xf, wv_ref[...].astype(jnp.float32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        vn = jnp.where(kv_cols < akv, vn, 0.0).reshape(S, KV, hd)
+    def _project():
+        x = x_ref[0]  # (S, dm), weights' dtype
+        qpos = (p + qoff_ref[...]).astype(jnp.float32)  # (H*S, 1)
+        q = _heads_to_rows(_proj(x, wq_ref, aq), H, hd)     # (H*S, hd)
+        kn = _heads_to_rows(_proj(x, wk_ref, akv), KV, hd)  # (KV*S, hd)
+        vn = _heads_to_rows(_proj(x, wv_ref, akv), KV, hd)
         if use_rope:
             q = _rope_rows(q, qpos, rope_theta)
-            kn = _rope_rows(kn, qpos, rope_theta)
+            kn = _rope_rows(kn, qpos[:KV * S], rope_theta)
         # candidates are returned RAW (commit re-quantizes); attention uses
         # the round trip when the cache is int8
-        kn_ref[0] = kn.astype(kn_ref.dtype)
-        vn_ref[0] = vn.astype(vn_ref.dtype)
+        kn_ref[0] = kn.reshape(KV, S, hd).transpose(1, 0, 2).astype(
+            kn_ref.dtype)
+        vn_ref[0] = vn.reshape(KV, S, hd).transpose(1, 0, 2).astype(
+            vn_ref.dtype)
         if quant:
             ksc = jnp.max(jnp.abs(kn), axis=-1, keepdims=True) / 127.0
             vsc = jnp.max(jnp.abs(vn), axis=-1, keepdims=True) / 127.0
@@ -673,9 +761,9 @@ def _verify_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
                   * vsc.astype(jnp.bfloat16).astype(jnp.float32))
         else:
             ke, ve = kn, vn
-        q_s[...] = q.transpose(1, 0, 2).reshape(H * S, hd)
-        ke_s[...] = ke.transpose(1, 0, 2).reshape(KV * S, hd)
-        ve_s[...] = ve.transpose(1, 0, 2).reshape(KV * S, hd)
+        q_s[...] = q
+        ke_s[...] = ke
+        ve_s[...] = ve
         m_s[...] = jnp.full_like(m_s, KERNEL_NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
@@ -684,35 +772,16 @@ def _verify_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
 
     @pl.when(live)
     def _tile():
-        k = k_ref[0].astype(jnp.float32)  # (bk, KV, hd)
-        v = v_ref[0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0].astype(jnp.float32)
-            v = v * vs_ref[0].astype(jnp.float32)
-        kt = k.transpose(1, 0, 2)
-        vt = v.transpose(1, 0, 2)
+        kt, vt = _load_kv_tile(k_ref, ks_ref, v_ref, vs_ref, quant)
         qg = q_s[...].reshape(KV, G * S, hd)
         s = jax.lax.dot_general(qg, kt, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        kp = kpos_ref[0]  # (bk,)
-        row_qpos = p + row_offs  # (G*S,)
-        valid = jnp.logical_and(kp[None, :] >= 0,
-                                kp[None, :] <= row_qpos[:, None])
+        kp = _verify_kpos(p, ik, bk, nkc * bk, window)  # (1, 1, bk)
+        row_qpos = (p + qoff_ref[...][:G * S]).reshape(1, G * S, 1)
+        valid = (kp >= 0) & (kp <= row_qpos)
         if window:
-            valid = jnp.logical_and(valid,
-                                    kp[None, :] > row_qpos[:, None] - window)
-        s = jnp.where(valid[None], s, KERNEL_NEG_INF)
-        m_prev = m_s[...].reshape(KV, G * S, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.where(valid[None], jnp.exp(s - m_new), 0.0)
-        l_s[...] = (l_s[...].reshape(KV, G * S, 1) * alpha
-                    + jnp.sum(pexp, axis=-1, keepdims=True)).reshape(H * S, 1)
-        pv = jax.lax.dot_general(pexp, vt, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        acc_s[...] = (acc_s[...].reshape(KV, G * S, hd) * alpha
-                      + pv).reshape(H * S, hd)
-        m_s[...] = m_new.reshape(H * S, 1)
+            valid = valid & (kp > row_qpos - window)
+        _online_softmax_update(s, valid, vt, m_s, l_s, acc_s, KV, G * S, hd)
 
     @pl.when(ik == nkc)
     def _finish():
@@ -721,23 +790,22 @@ def _verify_kernel(lens_ref, pos_ref, aq_ref, akv_ref, tbl_ref,
         ve = ve_s[...].reshape(KV, S, hd)
         s_e = jax.lax.dot_general(qg, ke, (((2,), (2,)), ((0,), (0,))),
                                   preferred_element_type=jnp.float32) * scale
-        emask = jnp.tile(ext_ref[...] != 0, (G, 1))  # (G*S, S) static mask
-        s_e = jnp.where(emask[None], s_e, KERNEL_NEG_INF)
+        emask = (ext_ref[...] != 0).reshape(1, G * S, S)
+        s_e = jnp.where(emask, s_e, KERNEL_NEG_INF)
         m_prev = m_s[...].reshape(KV, G * S, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s_e, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.where(emask[None], jnp.exp(s_e - m_new), 0.0)
+        pexp = jnp.where(emask, jnp.exp(s_e - m_new), 0.0)
         l = (l_s[...].reshape(KV, G * S, 1) * alpha
              + jnp.sum(pexp, axis=-1, keepdims=True))
         pv = jax.lax.dot_general(pexp, ve, (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         acc = acc_s[...].reshape(KV, G * S, hd) * alpha + pv
-        out = acc / jnp.maximum(l, 1e-20)  # (KV, G*S, hd)
-        oh = out.reshape(KV, G, S, hd).transpose(2, 0, 1, 3).reshape(S, H * hd)
-        ocols = jax.lax.broadcasted_iota(jnp.int32, (1, H * hd), 1)
-        oh = jnp.where(ocols < aq, oh, 0.0)
-        o = jax.lax.dot_general(oh, wo_ref[...].astype(jnp.float32),
-                                (((1,), (0,)), ((), ())),
+        out = (acc / jnp.maximum(l, 1e-20)).reshape(H * S, hd)
+        oh = _rows_to_heads(out, H, S).astype(wo_ref.dtype)  # (S, H*hd)
+        ocols = jax.lax.broadcasted_iota(jnp.int32, oh.shape, 1)
+        oh = jnp.where(ocols < aq, oh, jnp.zeros_like(oh))
+        o = jax.lax.dot_general(oh, wo_ref[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         o_ref[0] = o.astype(o_ref.dtype)
 
@@ -761,32 +829,27 @@ def _verify_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, node_depth,
     dt = x.dtype
     B, S, dm = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    G = H // KV
     window = cfg.sliding_window
     quant = bool(cfg.kv_quant)
     pos = jnp.asarray(pos, jnp.int32)
     offs = (np.arange(S, dtype=np.int32) if node_depth is None
             else np.asarray(node_depth, np.int32))
-    ext_ok = _ext_mask_np(offs, window, tree_bias)
+    # per-row static operands in the kernel's head-major row order
+    qoff = np.tile(offs, H)[:, None].astype(np.int32)             # (H*S, 1)
+    ext = np.tile(_ext_mask_np(offs, window, tree_bias), (G, 1))  # (G*S, S)
     pool, table, bk, nkc, Sc = _as_pool(cache, pages, page_size, B)
-    kpos_c = _cache_kpos(pos, Sc, window).astype(jnp.int32)
     lens = (jnp.where(pos > 0, Sc, 0) if window
             else jnp.minimum(pos, Sc)).astype(jnp.int32)
 
-    wq, wk, wv, wo = params["wq"], params["wk"], params["wv"], params["wo"]
-    d_out = wo.shape[1]
+    weights = [params[n].astype(dt) for n in ("wq", "wk", "wv", "wo")]
+    d_out = weights[3].shape[1]
+    cache_dt = pool["k"].dtype
     if quant:
         ksp, vsp = pool["k_scale"], pool["v_scale"]
     else:
         ksp = jnp.zeros((1, bk, KV, 1), jnp.float32)
         vsp = ksp
-
-    def _pool_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
-        return (tbl_[b, jnp.minimum(ik, nkc - 1)], 0, 0, 0)
-
-    def _scale_map(b, ik, lens_, pos_, aq_, akv_, tbl_):
-        if quant:
-            return (tbl_[b, jnp.minimum(ik, nkc - 1)], 0, 0, 0)
-        return (0, 0, 0, 0)
 
     kern = functools.partial(
         _verify_kernel, bk=bk, nkc=nkc, S=S, H=H, KV=KV, hd=hd,
@@ -795,20 +858,11 @@ def _verify_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, node_depth,
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, nkc + 1),
-        in_specs=[
-            pl.BlockSpec((1, S, dm), lambda b, ik, *s: (b, 0, 0)),
-            pl.BlockSpec((dm, H * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((dm, KV * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((dm, KV * hd), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((H * hd, d_out), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((1, bk, KV, hd), _pool_map),
-            pl.BlockSpec((1, bk, KV, 1), _scale_map),
-            pl.BlockSpec((1, bk, KV, hd), _pool_map),
-            pl.BlockSpec((1, bk, KV, 1), _scale_map),
-            pl.BlockSpec((1, bk), lambda b, ik, *s: (b, jnp.minimum(ik, nkc - 1))),
-            pl.BlockSpec((1, S), lambda b, ik, *s: (0, 0)),
-            pl.BlockSpec((S, S), lambda b, ik, *s: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, S, dm), lambda b, ik, *s: (b, 0, 0))]
+        + _weight_specs(dm, H * hd, KV * hd, d_out)
+        + _kv_specs(bk, KV, hd, nkc, quant)
+        + [pl.BlockSpec((H * S, 1), lambda b, ik, *s: (0, 0)),
+           pl.BlockSpec((G * S, S), lambda b, ik, *s: (0, 0))],
         out_specs=[
             pl.BlockSpec((1, S, d_out), lambda b, ik, *s: (b, 0, 0)),
             pl.BlockSpec((1, S, KV, hd), lambda b, ik, *s: (b, 0, 0, 0)),
@@ -823,6 +877,7 @@ def _verify_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, node_depth,
             pltpu.VMEM((H * S, hd), jnp.float32),
         ],
     )
+    kv_bytes = 2 * bk * KV * (hd * cache_dt.itemsize + 128 * 4)
     out, k_new, v_new = pl.pallas_call(
         kern, grid_spec=gs,
         out_shape=[
@@ -830,16 +885,18 @@ def _verify_pallas(params, x, cache, pos, cfg, *, a_q, a_kv, node_depth,
             jax.ShapeDtypeStruct((B, S, KV, hd), dt),
             jax.ShapeDtypeStruct((B, S, KV, hd), dt),
         ],
+        compiler_params=_compiler_params(
+            weights, S * (dm + d_out) * dt.itemsize, kv_bytes,
+            (3 * H + 2 * KV) * S * 128 * 4),
         interpret=interpret,
     )(lens, pos, jnp.asarray(a_q, jnp.int32) if a_q is not None
       else jnp.full((B,), H * hd, jnp.int32),
       jnp.asarray(a_kv, jnp.int32) if a_kv is not None
       else jnp.full((B,), KV * hd, jnp.int32),
       table.astype(jnp.int32),
-      x, wq, wk, wv, wo,
-      pool["k"], ksp, pool["v"], vsp, kpos_c,
-      jnp.asarray(offs, jnp.int32)[None, :],
-      jnp.asarray(ext_ok, jnp.int8))
+      x, *weights,
+      pool["k"], ksp, pool["v"], vsp,
+      jnp.asarray(qoff), jnp.asarray(ext, jnp.int32))
     return out, {"k": k_new, "v": v_new}
 
 

@@ -150,7 +150,7 @@ def morph_matmul(x: jnp.ndarray, w: jnp.ndarray,
                  active_n: ActiveDim = None,
                  active_k: ActiveDim = None,
                  *, block: Tuple[int, int, int] = (128, 128, 128),
-                 interpret: bool = True,
+                 interpret: bool = False,
                  impl: str = "pallas") -> jnp.ndarray:
     """x: (M, K) or (B, M, K); w: (K, N). active_* are dynamic scalars or,
     for batched x, per-batch ``(B,)`` vectors. ``impl``: "pallas" | "ref" |
@@ -180,3 +180,49 @@ def morph_matmul(x: jnp.ndarray, w: jnp.ndarray,
                              interpret=interpret, impl=impl)
     out = out[:, :M, :N]
     return out if batched else out[0]
+
+
+def sharded_morph_matmul(mesh, x: jnp.ndarray, w: jnp.ndarray,
+                         active_n: ActiveDim = None,
+                         active_k: ActiveDim = None, *, axis: str = "model",
+                         interpret: bool = False,
+                         impl: str = "pallas") -> jnp.ndarray:
+    """``morph_matmul`` on a device mesh: one kernel per shard.
+
+    The compiler cannot partition a Mosaic kernel, so the kernel runs inside
+    ``shard_map``. A row-gated projection (``active_k``) splits w's rows and
+    x's last dim over ``axis`` and sums the partial products there; any
+    other splits w's columns. Each shard clips the global active width to
+    its own slice. A dim that ``axis`` does not divide stays replicated, and
+    other mesh axes replicate everything. The row split sums partials in
+    x.dtype: one extra rounding of the output at bf16.
+    x: (B, M, K); w: (K, N); active_*: scalars or (B,) vectors.
+    """
+    B, _, K = x.shape
+    N = w.shape[1]
+    tp = dict(mesh.shape).get(axis, 1)
+    an = _as_active(active_n, N, B)
+    ak = _as_active(active_k, K, B)
+    split = None
+    if tp > 1 and active_k is not None and K % tp == 0:
+        split = "k"
+    elif tp > 1 and N % tp == 0:
+        split = "n"
+
+    def body(x, w, an, ak):
+        if split == "n":
+            n = w.shape[1]
+            an = jnp.clip(an - jax.lax.axis_index(axis) * n, 0, n)
+        elif split == "k":
+            k = w.shape[0]
+            ak = jnp.clip(ak - jax.lax.axis_index(axis) * k, 0, k)
+        y = morph_matmul(x, w, an, ak, interpret=interpret, impl=impl)
+        return jax.lax.psum(y, axis) if split == "k" else y
+
+    P = jax.sharding.PartitionSpec
+    x_spec = P(None, None, axis) if split == "k" else P()
+    w_spec = {"k": P(axis, None), "n": P(None, axis)}.get(split, P())
+    out_spec = P(None, None, axis) if split == "n" else P()
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(x_spec, w_spec, P(), P()),
+                         out_specs=out_spec, check_vma=False)(x, w, an, ak)

@@ -72,7 +72,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref, *, Q, 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
              B: jnp.ndarray, C: jnp.ndarray, *, chunk: int = 128,
-             interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             interpret: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (BH, S, hp); dt: (BH, S); A: (BH,); B, C: (BH, S, n)."""
     BH, S, hp = x.shape
     n = B.shape[-1]

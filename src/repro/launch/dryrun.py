@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat as _compat
 from repro.configs import SHAPE_BY_NAME, SHAPES, cell_applicable, get_config, list_archs
 from repro.configs.base import ModelConfig, MorphMode, ShapeCell
 from repro.core import elastic
@@ -168,7 +167,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, knobs: Knobs,
     set_bf16_grad_matmul(k.bf16_grad_matmul)
     t0 = time.time()
     try:
-        with _compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if cell.kind == "train":
                 lowered = _lower_train(cfg_exec, cell, mesh, k)
             elif cell.kind == "prefill":
@@ -210,7 +209,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, knobs: Knobs,
     hc = analyze_hlo(hlo_text, chips)
     flops_pd = hc.flops
     bytes_pd = hc.bytes
-    ca = _compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["cost"] = {
         "flops_per_device": flops_pd,
         "bytes_per_device": bytes_pd,

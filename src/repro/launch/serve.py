@@ -73,6 +73,7 @@ if _mesh_spec is not None:
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_config
 from repro.core import elastic
 from repro.launch.mesh import make_serve_mesh
@@ -188,6 +189,7 @@ def main(argv=None):
                          "bit-identical to a fixed-mode run)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     failure_plan = None
     if args.fail_at:

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.parallel.sharding import constrain
+from repro.parallel.sharding import constrain, current_mesh
 
 # ---------------------------------------------------------------------------
 # helpers
@@ -50,13 +50,20 @@ def morph_proj(x, w, active_n=None, active_k=None):
     are exactly zero; contraction rows >= active_k contribute nothing.
     ``active_n`` / ``active_k`` may be per-batch ``(B,)`` vectors — batch
     slots running *different* width modes share this single projection.
-    x: (B, S, d); w: (d, N).
+    Under a multi-device serving mesh the Pallas kernel runs per shard
+    (``sharded_morph_matmul``). x: (B, S, d); w: (d, N).
     """
-    from repro.kernels import morph_matmul as _mm  # local: keep layers import-light
+    # local: keep layers import-light
+    from repro.kernels import morph_matmul as _mm
+    from repro.kernels.morph_matmul import default_impl, sharded_morph_matmul
 
     if active_n is None and active_k is None:
         return matmul(x, w, x.dtype)
-    return _mm(x, w.astype(x.dtype), active_n, active_k, impl="auto")
+    w = w.astype(x.dtype)
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1 and default_impl() == "pallas":
+        return sharded_morph_matmul(mesh, x, w, active_n, active_k)
+    return _mm(x, w, active_n, active_k, impl="auto")
 
 
 # --- bf16-cotangent matmul (beyond-paper §Perf lever) -----------------------

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat as _compat
-
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -78,7 +76,7 @@ def pipeline_apply(stage_params, x, fn: Callable, mesh: Mesh, *,
         # stage wrote non-zeros, so a psum over the axis is a broadcast
         return jax.lax.psum(outputs, axis)
 
-    pp = _compat.shard_map(
+    pp = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P(axis), P(*([None] * xs.ndim))),
         out_specs=P(*([None] * xs.ndim)),

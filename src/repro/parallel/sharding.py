@@ -66,6 +66,12 @@ def activation_sharding(mesh: Mesh, specs: Dict[str, P]):
         _CTX.val = prev
 
 
+def current_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``activation_sharding`` context, if any."""
+    ctx = getattr(_CTX, "val", None)
+    return None if ctx is None else ctx[0]
+
+
 def constrain(x, name: str):
     ctx = getattr(_CTX, "val", None)
     if ctx is None:

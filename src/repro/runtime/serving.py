@@ -110,6 +110,7 @@ from repro.core.morph import (MorphController, make_serve_controller,
 from repro.core.neuroforge.analytical import estimate, estimate_mode
 from repro.core.neuroforge.hw import V5E, HardwareSpec
 from repro.core.neuroforge.space import DesignPoint
+from repro.kernels.fused_decode import default_impl as fused_impl
 from repro.models.model import (adopt_cache_slot, commit_verify,
                                 init_decode_cache, prefill,
                                 reset_cache_slots, verify_step)
@@ -628,6 +629,13 @@ class MeshExecutor(LocalExecutor):
              fused: bool = False) -> "MeshExecutor":
         super().bind(cfg, batch_size, cache_capacity, paged=paged,
                      fused=fused)
+        if fused and self.mesh.size > 1 and fused_impl() == "pallas":
+            # the compiler cannot partition a Mosaic kernel, and the
+            # superkernel reads a slot's whole KV sequence, which the mesh
+            # splits over the model axis
+            raise ValueError("the fused decode superkernel runs on one "
+                             "device; serve a multi-device mesh with "
+                             "fused=False")
         self.policy = self._policy_arg or SH.serve_policy(cfg, self.tp)
         if paged is not None:
             cstruct = jax.eval_shape(

@@ -370,3 +370,17 @@ def test_mesh_fused_engine_matches_local():
                          capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-2000:])
     assert "MESH_FUSED_OK" in out.stdout
+
+
+def test_mesh_executor_refuses_fused_pallas_on_many_devices(monkeypatch):
+    """Where the superkernel would compile to Mosaic, a mesh of more than
+    one device refuses ``fused=True`` at bind, before any compile."""
+    from repro.runtime import serving
+
+    # a mesh by shape alone: the test process has one CPU device
+    mesh = jax.sharding.AbstractMesh((1, 4), ("data", "model"))
+    cfg = smoke_config("tinyllama-1.1b")
+    monkeypatch.setattr(serving, "fused_impl", lambda: "pallas")
+    with pytest.raises(ValueError, match="one device"):
+        serving.MeshExecutor(mesh).bind(cfg, 2, 32, fused=True)
+    serving.MeshExecutor(mesh).bind(cfg, 2, 32, fused=False)

@@ -10,7 +10,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.launch.hlo_analysis import analyze_hlo, _wire_bytes
 
 # ring-cost formulas
@@ -19,7 +19,7 @@ assert _wire_bytes("all-gather", 100.0, 4) == 0.75 * 100.0
 assert _wire_bytes("collective-permute", 100.0, 4) == 100.0
 assert _wire_bytes("all-reduce", 100.0, 1) == 0.0
 
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def f(x, ws):
     def body(h, w):
@@ -29,7 +29,7 @@ def f(x, ws):
 
 xs = jax.ShapeDtypeStruct((32, 64), jnp.float32)
 ws = jax.ShapeDtypeStruct((5, 64, 64), jnp.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(
         f, in_shardings=(NamedSharding(mesh, P("data", "model")),
                          NamedSharding(mesh, P(None, "model", None))),
@@ -54,10 +54,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.runtime import compressed_psum, init_error_buffer
 
-mesh = compat.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 grads = {"w": jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4) / 10.0}
 errs = init_error_buffer({"w": grads["w"][0]})
@@ -66,9 +66,9 @@ def worker(g, e):
     red, new_e = compressed_psum({"w": g}, {"w": e}, "data")
     return red["w"], new_e["w"]
 
-f = compat.shard_map(worker, mesh=mesh, in_specs=(P("data"), P()),
-                     out_specs=(P(), P("data")), check_vma=False)
-with compat.set_mesh(mesh):
+f = jax.shard_map(worker, mesh=mesh, in_specs=(P("data"), P()),
+                  out_specs=(P(), P("data")), check_vma=False)
+with jax.set_mesh(mesh):
     red, _ = f(grads["w"], errs["w"])
 expected = np.mean(np.asarray(grads["w"]), axis=0)
 got = np.asarray(red)[0] if red.ndim == 2 else np.asarray(red)

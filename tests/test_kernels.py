@@ -153,6 +153,49 @@ def test_morph_matmul_pad_path_traces_once():
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-3)
 
 
+_SHARDED_MM_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from repro.kernels.morph_matmul import morph_matmul, sharded_morph_matmul
+from repro.launch.mesh import make_mesh
+
+kx, kw = jax.random.split(jax.random.PRNGKey(0))
+B, M, K = 3, 2, 256
+x = jax.random.normal(kx, (B, M, K), jnp.float32)
+# per-slot widths: full, partial (ends inside a shard), none
+cases = [(512, jnp.array([512, 200, 0]), None),    # column split
+         (512, None, jnp.array([K, 100, 0])),      # row split + psum
+         (130, jnp.array([130, 70, 1]), None)]     # 130 % 4: replicated
+for shape in ((1, 4), (2, 2)):
+    mesh = make_mesh(shape, ("data", "model"))
+    for n, an, ak in cases:
+        w = jax.random.normal(kw, (K, n), jnp.float32)
+        want = morph_matmul(x, w, an, ak, impl="pallas", interpret=True)
+        got = jax.jit(lambda x, w: sharded_morph_matmul(
+            mesh, x, w, an, ak, interpret=True))(x, w)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)
+print("SHARDED_MM_OK")
+"""
+
+
+def test_sharded_morph_matmul_matches_one_device():
+    """On 1x4 and 2x2 meshes of CPU devices, the per-shard kernel with
+    clipped active widths equals the one-device kernel."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    out = subprocess.run([sys.executable, "-c", _SHARDED_MM_SCRIPT],
+                         capture_output=True, text=True, timeout=600,
+                         env=dict(os.environ, PYTHONPATH=src))
+    assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-2000:])
+    assert "SHARDED_MM_OK" in out.stdout
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

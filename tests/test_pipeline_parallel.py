@@ -9,10 +9,10 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline_parallel import pipeline_apply, bubble_fraction
 
-mesh = compat.make_mesh((4, 2), ("pod", "model"))
+mesh = make_mesh((4, 2), ("pod", "model"))
 S, d = 4, 16
 ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) / d ** 0.5
 
@@ -20,7 +20,7 @@ def fn(w, h):
     return jax.nn.relu(h @ w)
 
 x = jax.random.normal(jax.random.PRNGKey(1), (8, d))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_pp = pipeline_apply(ws, x, fn, mesh, axis="pod", n_micro=4)
 h = x
 for s in range(S):

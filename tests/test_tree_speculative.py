@@ -20,6 +20,7 @@ import subprocess
 import sys
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -302,7 +303,7 @@ def _scan_carry_byte_sizes(jaxpr):
         for sub in eqn.params.values():
             subs = sub if isinstance(sub, (list, tuple)) else (sub,)
             for s in subs:
-                if isinstance(s, jax.core.ClosedJaxpr):
+                if isinstance(s, jax.extend.core.ClosedJaxpr):
                     out.extend(_scan_carry_byte_sizes(s.jaxpr))
     return out
 
