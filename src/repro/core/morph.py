@@ -36,7 +36,7 @@ from __future__ import annotations
 import bisect
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, Hashable, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, Hashable, List, Optional, Tuple
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -162,13 +162,17 @@ class MorphController:
         # per-set_mode-change structured event stream; bounded for long
         # serves. Lazy import: repro.runtime imports this module at package
         # init, so the reverse import must wait until construction time.
-        from repro.runtime.observability import EventStream
+        from repro.runtime.observability import Counter, EventStream, TraceRecorder
         self.switch_events = EventStream(
             "controller_mode_switch", ("dispatch", "from_mode", "to_mode"))
         self.last_step_s = 0.0  # latency of the most recent timed_step
         # injectable for deterministic tests / virtual-clock supervision
         # (the serving engine points it at its Observability clock)
         self.clock: Callable[[], float] = time.perf_counter
+        # phase spans and the build counter; the serving engine points them
+        # at its own recorder and its registry's ``engine_program_builds``
+        self.recorder = TraceRecorder()
+        self.builds = Counter("engine_program_builds")
         self._mode = self.modes[-1]  # full model by default
 
     @property
@@ -191,11 +195,19 @@ class MorphController:
                                     to_mode=mode.name)
         self._mode = mode
 
+    def build(self, key: Hashable, make: Callable[[], Any]) -> Any:
+        """``make()`` for a cache miss on the serving path (an executable or
+        a device operand), inside one ``engine.build`` span naming ``key``
+        and counted in ``builds``."""
+        with self.recorder.span("engine.build", key=str(key)):
+            self.builds.add()
+            return make()
+
     def _get(self, mode: MorphMode) -> Callable:
         key = self._compile_key(mode)
         fn = self._compiled.get(key)
         if fn is None:
-            fn = self._factory(mode)
+            fn = self.build(key, lambda: self._factory(mode))
             self._compiled[key] = fn
             self.stats["compiles"] += 1
         self.last_dispatch[key] = self.stats["dispatches"]
@@ -218,7 +230,7 @@ class MorphController:
         first use, counted in ``stats['compiles']`` like any mode step)."""
         fn = self._compiled.get(key)
         if fn is None:
-            fn = self._aux_factories[key]()
+            fn = self.build(key, self._aux_factories[key])
             self._compiled[key] = fn
             self.stats["compiles"] += 1
         self.last_dispatch[key] = self.stats["dispatches"]
@@ -295,10 +307,12 @@ class MorphController:
         """
         m = self._mode if mode is None else mode
         self.stats["dispatches"] += 1
-        t0 = self.clock()
-        out = self._get(m)(*args, **kw)
-        jax.block_until_ready(out)
-        dt = self.clock() - t0
+        fn = self._get(m)
+        with self.recorder.span("engine.device"):
+            t0 = self.clock()
+            out = fn(*args, **kw)
+            jax.block_until_ready(out)
+            dt = self.clock() - t0
         self.telemetry[m.name].record(dt, tokens)
         self.last_step_s = dt
         return out

@@ -13,8 +13,16 @@ per-request lifecycle spans (submit -> admit/prefill -> first token ->
 decode ticks -> complete/expire, with failover replays marked) in Chrome
 trace-event format, directly loadable in Perfetto / chrome://tracing.
 Disabled (the default) every record method returns before touching any
-state, so the tick path pays one attribute check; the ``--obs-smoke`` CI
-shard gates the enabled path at <3% p50 decode-step overhead.
+state, so the tick path pays one attribute check.
+
+Its ``span`` context manager marks the engine's phases (``engine.step``,
+``engine.decode``, ``engine.device``, ...) in two sinks: a running JAX
+profiler session (``jax.profiler.TraceAnnotation``, beside the device's
+timeline) and, when the recorder is enabled, its Chrome trace, on a lane
+of their own. With neither, ``span`` returns one shared no-op. Every
+``Observability`` also installs, once per process, a ``gc.callbacks`` hook
+that marks each garbage collection as a ``host.gc`` span and observes its
+pause in the process-wide ``host_gc_pause_ms`` histogram.
 
 Both share an injectable ``clock`` so the supervisor's virtual-time
 ``run_trace`` and the chaos tests stay deterministic under tracing.
@@ -22,11 +30,14 @@ Both share an injectable ``clock`` so the supervisor's virtual-time
 from __future__ import annotations
 
 import bisect
+import gc
 import json
 import math
 import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -37,6 +48,7 @@ __all__ = [
     "TraceRecorder",
     "Observability",
     "DEFAULT_LATENCY_BUCKETS_MS",
+    "gc_pauses",
 ]
 
 # Fixed histogram buckets (milliseconds) spanning sub-ms kernel launches to
@@ -354,6 +366,60 @@ class MetricsRegistry:
             self.events(n, ss["fields"], maxlen=ss["maxlen"]).load_state(ss)
 
 
+class _NoSpan:
+    """What ``TraceRecorder.span`` returns when nothing records: one shared
+    instance, entered and left at the cost of two method calls."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **args: Any) -> None:
+        """Args known only inside the span (ignored here)."""
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One phase span, written to the profiler's trace (if a session runs)
+    and to the recorder's Chrome trace (if it is enabled)."""
+
+    __slots__ = ("rec", "name", "args", "ann", "t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str, args: Dict[str, Any]):
+        self.rec, self.name, self.args = rec, name, args
+        self.ann = None
+
+    def __enter__(self) -> "_Span":
+        if TraceAnnotation.is_enabled():
+            self.ann = TraceAnnotation(self.name, **self.args)
+            self.ann.__enter__()
+        if self.rec.enabled:
+            self.t0 = self.rec.clock()
+        return self
+
+    def set(self, **args: Any) -> None:
+        """Add args known only inside the span (e.g. requests admitted)."""
+        self.args.update(args)
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        rec = self.rec
+        if rec.enabled:
+            t1 = rec.clock()
+            rec._push(dict(ph="X", name=self.name, cat="phase", pid=0, tid=1,
+                           ts=self.t0 * 1e6, dur=(t1 - self.t0) * 1e6,
+                           args=self.args))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+
+
 class TraceRecorder:
     """Chrome trace-event recorder (Perfetto / chrome://tracing format).
 
@@ -361,8 +427,10 @@ class TraceRecorder:
     pid/tid (the engine tick loop is single-threaded, so spans never
     overlap); request lifecycles are async spans (``ph`` b/n/e) keyed by
     rid, so Perfetto renders a lane per request with instants for admit,
-    first token, and failover replays. Every record method bails on the
-    first line when disabled — the hot path pays one predictable branch."""
+    first token, and failover replays. Phase spans (``span``) are complete
+    events (``ph`` X) on a lane of their own (tid 1), where they nest. Every
+    record method bails on the first line when disabled — the hot path pays
+    one predictable branch."""
 
     def __init__(self, enabled: bool = False,
                  clock: Callable[[], float] = time.perf_counter,
@@ -388,6 +456,19 @@ class TraceRecorder:
                         ts=t0 * 1e6, args=args))
         self._push(dict(ph="E", name=site, cat="launch", pid=0, tid=0,
                         ts=t1 * 1e6))
+
+    # -- phase spans -------------------------------------------------------
+    def span(self, name: str, **args: Any):
+        """Context manager marking one engine phase ``name`` with ``args``.
+
+        Inside a running JAX profiler session it enters a
+        ``jax.profiler.TraceAnnotation`` (the span lands in the trace's host
+        plane, on the device plane's clock); when the recorder is enabled it
+        also records a Chrome complete event on lane tid 1. With neither it
+        returns the shared no-op."""
+        if not self.enabled and not TraceAnnotation.is_enabled():
+            return _NO_SPAN
+        return _Span(self, name, args)
 
     # -- request lifecycle spans ------------------------------------------
     def request_begin(self, rid: int, t: Optional[float] = None, **args: Any) -> None:
@@ -434,10 +515,52 @@ class TraceRecorder:
         self.events = [dict(e) for e in st["events"]]
 
 
+class _GcPauses:
+    """``gc.callbacks`` hook: each collection is a ``host.gc`` span in a
+    running profiler session, and its pause (host wall clock, whatever
+    clock the engines run on) an observation of ``hist``."""
+
+    def __init__(self):
+        self.hist = Histogram("host_gc_pause_ms")
+        self._t0: Optional[float] = None
+        self._ann: Optional[TraceAnnotation] = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            if TraceAnnotation.is_enabled():
+                self._ann = TraceAnnotation("host.gc",
+                                            generation=info["generation"])
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.hist.observe((time.perf_counter() - self._t0) * 1e3)
+            self._t0 = None
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+
+
+_GC_PAUSES: Optional[_GcPauses] = None
+
+
+def gc_pauses() -> Histogram:
+    """The process-wide ``host_gc_pause_ms`` histogram, its ``gc.callbacks``
+    hook installed on first use. It lives outside every registry: the
+    process collects for all engines alike, and an engine's snapshot must
+    not carry (or restore over) the process's pauses."""
+    global _GC_PAUSES
+    if _GC_PAUSES is None:
+        _GC_PAUSES = _GcPauses()
+        gc.callbacks.append(_GC_PAUSES)
+    return _GC_PAUSES.hist
+
+
 class Observability:
     """Facade bundling one registry + one recorder + one clock, passed down
     through engine -> controller -> executor -> supervisor so the whole
-    stack shares a single export surface."""
+    stack shares a single export surface. The registry exports the
+    process's GC pauses as gauges (``host_gc_pauses``, their total
+    ``host_gc_pause_ms_sum`` and the recent ``host_gc_pause_ms_p99``)."""
 
     def __init__(self, trace: bool = False,
                  clock: Callable[[], float] = time.perf_counter,
@@ -447,6 +570,14 @@ class Observability:
         self.registry = registry if registry is not None else MetricsRegistry()
         self.recorder = TraceRecorder(enabled=trace, clock=clock,
                                       max_events=max_trace_events)
+        self.gc_pauses = gc_pauses()
+        self.registry.register_callback(self._gc_gauges, key="host_gc")
+
+    def _gc_gauges(self) -> Dict[str, float]:
+        h = self.gc_pauses
+        return {"host_gc_pauses": float(h.count),
+                "host_gc_pause_ms_sum": h.sum,
+                "host_gc_pause_ms_p99": h.p99}
 
     def state_dict(self) -> Dict[str, Any]:
         return dict(registry=self.registry.state_dict(),

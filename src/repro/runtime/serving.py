@@ -1211,6 +1211,8 @@ class ServingEngine:
              "mode", "queued_interactive", "queued_batch"))
         reg.attach_events(self.ctrl.switch_events)
         self.ctrl.clock = self._clock
+        self.ctrl.recorder = self._rec
+        self.ctrl.builds = reg.counter("engine_program_builds")
         reg.register_callback(self._metric_gauges, key="engine")
         self.spec_draft_launches = 0
         self.spec_verify_launches = 0
@@ -1281,10 +1283,27 @@ class ServingEngine:
         if active is None:
             if len(self._active_cache) > 1024:  # oscillation backstop
                 self._active_cache.clear()
-            active = jax.tree_util.tree_map(
-                self.executor.put, elastic.active_widths_batch(self.cfg, widths))
+            active = self.ctrl.build(key, lambda: jax.tree_util.tree_map(
+                self.executor.put, elastic.active_widths_batch(self.cfg, widths)))
             self._active_cache[key] = active
         return active
+
+    def _prefill_for(self, plen: int, depth: int,
+                     make: Callable[[], Callable]) -> Callable:
+        """The compiled prefill for ``(plen, depth)``, built by ``make`` on
+        a miss."""
+        key = (plen, depth)
+        fn = self._prefills.get(key)
+        if fn is None:
+            # backstop for unbounded prompt-length churn (cf. _active_cache):
+            # a long-lived engine must not retain one executable per distinct
+            # prompt length forever. Length bucketing would cap compiles at
+            # O(log capacity) but needs padding-safe prefill (SSM state sees
+            # every padded token), so the simple bound stands in for now.
+            if len(self._prefills) > 256:
+                self._prefills.clear()
+            fn = self._prefills[key] = self.ctrl.build(key, make)
+        return fn
 
     # -- observability ------------------------------------------------------
 
@@ -1547,7 +1566,10 @@ class ServingEngine:
                     kept.append(r)
             self._queues[cls] = kept
 
-    def _admit(self, now_s: float = 0.0) -> None:
+    def _admit(self, now_s: float = 0.0) -> int:
+        """Admit queued requests into the admission mode's free slots (a
+        batched reset for token-fed ones, a prefill each for the rest).
+        Returns how many were admitted."""
         self._expire_queued(now_s)
         g = self.groups[self.admission_mode.depth]
         mask = np.zeros(self.batch_size, bool)
@@ -1591,6 +1613,7 @@ class ServingEngine:
                 g.paging.host_pos[mask] = 0
         for slot, req in prefills:
             self._admit_prefill(g, slot, req, now_s)
+        return int(mask.sum()) + len(prefills)
 
     def _complete(self, g: _DepthGroup, slot: int, req: Request,
                   now_s: float) -> None:
@@ -1615,18 +1638,8 @@ class ServingEngine:
         last-position logits.
         """
         plen = len(prompt)
-        key = (plen, g.depth)
-        fn = self._prefills.get(key)
-        if fn is None:
-            # backstop for unbounded prompt-length churn (cf. _active_cache):
-            # a long-lived engine must not retain one executable per distinct
-            # prompt length forever. Length bucketing would cap compiles at
-            # O(log capacity) but needs padding-safe prefill (SSM state sees
-            # every padded token), so the simple bound stands in for now.
-            if len(self._prefills) > 256:
-                self._prefills.clear()
-            fn = self.executor.prefill_fn(plen, g.depth)
-            self._prefills[key] = fn
+        fn = self._prefill_for(
+            plen, g.depth, lambda: self.executor.prefill_fn(plen, g.depth))
         toks = self.executor.put(np.asarray([prompt], np.int32))
         slot_op = self.executor.put(np.int32(slot))
         logits, pre = fn(self.params, toks, slot_op)
@@ -1674,13 +1687,9 @@ class ServingEngine:
             pg.table[slot, :ncp] = pages_list
             wmask = np.arange(ncp) >= len(shared)
         pg.host_pos[slot] = plen
-        key = (plen, g.depth)
-        fn = self._prefills.get(key)
-        if fn is None:
-            if len(self._prefills) > 256:
-                self._prefills.clear()
-            fn = self.executor.prefill_adopt_fn(plen, g.depth, ncp)
-            self._prefills[key] = fn
+        fn = self._prefill_for(
+            plen, g.depth,
+            lambda: self.executor.prefill_adopt_fn(plen, g.depth, ncp))
         toks = self.executor.put(np.asarray([prompt], np.int32))
         slot_op = self.executor.put(np.int32(slot))
         logits, g.cache = fn(
@@ -1694,25 +1703,30 @@ class ServingEngine:
     def _admit_prefill(self, g: _DepthGroup, slot: int, req: Request,
                        now_s: float) -> None:
         """Consume the whole prompt in one compiled prefill + adoption."""
-        self.executor.launch("prefill")
-        t0 = self._clock()
-        if g.paging is not None:
-            logits = self._prefill_launch_paged(g, slot, req.prompt)
-        else:
-            logits = self._prefill_launch(g, slot, req.prompt)
-        req.prefilled = True
-        # the prefill's last-position logits yield the first generated token
-        # (same contract as the decode step that eats the last prompt token);
-        # under sampled serving it must come from the slot's sample stream,
-        # not argmax — both admission paths serve the same distribution
-        if self.temperature > 0:
-            s_op = self.executor.put(np.uint32(self.step_count))
-            nxt = int(np.asarray(self._sample_fn(
-                logits[:, 0], g.keys[slot:slot + 1], self._temp_op, s_op))[0])
-        else:
-            nxt = int(np.asarray(jnp.argmax(logits[0, 0, : self.cfg.vocab_size])))
-        jax.block_until_ready(g.cache)
-        t1 = self._clock()
+        with self._rec.span("engine.prefill", plen=len(req.prompt),
+                            depth=g.depth):
+            self.executor.launch("prefill")
+            t0 = self._clock()
+            if g.paging is not None:
+                logits = self._prefill_launch_paged(g, slot, req.prompt)
+            else:
+                logits = self._prefill_launch(g, slot, req.prompt)
+            req.prefilled = True
+            # the prefill's last-position logits yield the first generated
+            # token (same contract as the decode step that eats the last
+            # prompt token); under sampled serving it must come from the
+            # slot's sample stream, not argmax — both admission paths serve
+            # the same distribution
+            if self.temperature > 0:
+                s_op = self.executor.put(np.uint32(self.step_count))
+                nxt = int(np.asarray(self._sample_fn(
+                    logits[:, 0], g.keys[slot:slot + 1], self._temp_op,
+                    s_op))[0])
+            else:
+                nxt = int(np.asarray(
+                    jnp.argmax(logits[0, 0, : self.cfg.vocab_size])))
+            jax.block_until_ready(g.cache)
+            t1 = self._clock()
         self.prefill_s += t1 - t0
         self.prefills += 1
         self.prefill_prompt_tokens += len(req.prompt)
@@ -1774,74 +1788,104 @@ class ServingEngine:
         plan = self._spec_plan[g.depth]
         kind, shape = sel
         site = "tree_verify" if kind == "tree" else "verify"
-        # launch boundary BEFORE any host page bookkeeping mutates: an
-        # injected loss here leaves the tick entirely un-executed, which is
-        # what makes the supervisor's pre-tick snapshot an exact replay point
-        self.executor.launch(site)
-        if kind == "tree":
-            draft = self.ctrl.aux_step(
-                tree_draft_compile_key(plan.draft_depth, shape))
-            verify = self.ctrl.aux_step(
-                tree_verify_compile_key(g.depth, shape))
-            depth_budget = len(shape)  # max accepted drafts per launch
-        else:
-            draft = self.ctrl.aux_step(
-                draft_compile_key(plan.draft_depth, shape))
-            verify = self.ctrl.aux_step(verify_compile_key(g.depth, shape))
-            depth_budget = shape
-        toks = np.zeros((self.batch_size, 1), np.int32)
-        for i in active_ix:
-            toks[i, 0] = g.slots[i].next_input()
-        active = self._active_for(g.widths)
-        tok_op = self.executor.put(toks)
-        s_op = self.executor.put(np.uint32(self.step_count))
-        pg = g.paging
-        extra = ()
-        if pg is not None:
-            # grow every active slot's mapping to cover the deepest draft
-            # write (root + depth_budget positions) and privatize any shared
-            # page in that range; the speculative executables always see the
-            # FULL-width table (their compile keys are not bucketed)
-            for i in active_ix:
-                pos = int(pg.host_pos[i])
-                pg.ensure_slot(i, pos + depth_budget)
-                for src, dst in pg.cow_pairs(i, pos, pos + depth_budget):
-                    g.cache = self._copy_page(
-                        g.cache, self.executor.put(np.int32(src)),
-                        self.executor.put(np.int32(dst)))
-            extra = (self.executor.put(pg.table[:, :pg.cap_pages].copy()),)
-        t0 = self._clock()
-        if kind == "tree":
-            ttoks, dlg = draft(self.params, g.cache, tok_op, active, g.keys,
-                               self._temp_op, s_op, *extra)
-            out, n_acc, g.cache = verify(self.params, g.cache, ttoks, dlg,
-                                         active, g.keys, self._temp_op, s_op,
-                                         *extra)
-        else:
-            dtoks, dlg = draft(self.params, g.cache, tok_op, active, g.keys,
-                               self._temp_op, s_op, *extra)
-            full = jnp.concatenate([tok_op, dtoks], axis=1)
-            out, n_acc, g.cache = verify(self.params, g.cache, full, dlg,
-                                         active, g.keys, self._temp_op, s_op,
-                                         *extra)
-        out_h = np.asarray(out)
-        n_acc_h = np.asarray(n_acc)
-        jax.block_until_ready(g.cache)
-        dt = self._clock() - t0
-        self.ctrl.stats["dispatches"] += 2
-        self.ctrl.last_step_s = dt
-        self.spec_draft_launches += 1
-        self.spec_verify_launches += 1
-        if kind == "tree":
-            self.spec_tree_launches += 1
+        rec = self._rec
+        rows = len(active_ix)
+        with rec.span("engine.draft", depth=plan.draft_depth, rows=rows,
+                      shape=str(shape)):
+            # launch boundary BEFORE any host page bookkeeping mutates: an
+            # injected loss here leaves the tick entirely un-executed, which
+            # is what makes the supervisor's pre-tick snapshot an exact
+            # replay point
+            self.executor.launch(site)
+            if kind == "tree":
+                draft = self.ctrl.aux_step(
+                    tree_draft_compile_key(plan.draft_depth, shape))
+                verify = self.ctrl.aux_step(
+                    tree_verify_compile_key(g.depth, shape))
+                depth_budget = len(shape)  # max accepted drafts per launch
+            else:
+                draft = self.ctrl.aux_step(
+                    draft_compile_key(plan.draft_depth, shape))
+                verify = self.ctrl.aux_step(
+                    verify_compile_key(g.depth, shape))
+                depth_budget = shape
+            pg = g.paging
+            with rec.span("engine.inputs"):
+                tok_op, active = self._decode_inputs(g, active_ix)
+                s_op = self.executor.put(np.uint32(self.step_count))
+                extra = ()
+                if pg is not None:
+                    # grow every active slot's mapping to cover the deepest
+                    # draft write (root + depth_budget positions) and
+                    # privatize any shared page in that range; the
+                    # speculative executables always see the FULL-width
+                    # table (their compile keys are not bucketed)
+                    for i in active_ix:
+                        pos = int(pg.host_pos[i])
+                        pg.ensure_slot(i, pos + depth_budget)
+                        for src, dst in pg.cow_pairs(i, pos,
+                                                     pos + depth_budget):
+                            g.cache = self._copy_page(
+                                g.cache, self.executor.put(np.int32(src)),
+                                self.executor.put(np.int32(dst)))
+                    extra = (self.executor.put(
+                        pg.table[:, :pg.cap_pages].copy()),)
+            t0 = self._clock()
+            with rec.span("engine.device"):
+                cand, dlg = draft(self.params, g.cache, tok_op, active,
+                                  g.keys, self._temp_op, s_op, *extra)
+                if kind != "tree":  # verify scores root + the K drafts
+                    cand = jnp.concatenate([tok_op, cand], axis=1)
+        with rec.span("engine.verify", depth=g.depth, rows=rows,
+                      shape=str(shape)):
+            with rec.span("engine.device"):
+                out, n_acc, g.cache = verify(self.params, g.cache, cand, dlg,
+                                             active, g.keys, self._temp_op,
+                                             s_op, *extra)
+                jax.block_until_ready((out, n_acc, g.cache))
+            with rec.span("engine.readback"):
+                out_h = np.asarray(out)
+                n_acc_h = np.asarray(n_acc)
+            dt = self._clock() - t0
+            with rec.span("engine.commit"):
+                self.ctrl.stats["dispatches"] += 2
+                self.ctrl.last_step_s = dt
+                self.spec_draft_launches += 1
+                self.spec_verify_launches += 1
+                if kind == "tree":
+                    self.spec_tree_launches += 1
+                if pg is not None:
+                    # mirror commit_verify: pos += n_accepted + 1 for EVERY
+                    # slot (free slots drift harmlessly — admission resets
+                    # both counters)
+                    pg.host_pos += np.asarray(n_acc_h, np.int64) + 1
+                rids = ([g.slots[i].rid for i in active_ix] if rec.enabled
+                        else None)
+                produced = self._commit_accepted(g, active_ix, out_h, n_acc_h,
+                                                 now_s)
+                self.spec_generated_tokens += produced
+                self._h_spec.observe(dt * 1e3)
+                if rec.enabled:
+                    rec.launch(
+                        site, t0, t0 + dt, depth=g.depth, rids=rids,
+                        occupancy=len(active_ix), tokens=produced,
+                        widths=[g.widths[i] for i in active_ix],
+                        key=list(tree_verify_compile_key(g.depth, shape)
+                                 if kind == "tree"
+                                 else verify_compile_key(g.depth, shape)))
+                self._spec_feedback(g, plan, sel, depth_budget,
+                                    [int(n_acc_h[i]) for i in active_ix], dt)
+        return dt
 
-        if pg is not None:
-            # mirror commit_verify: pos += n_accepted + 1 for EVERY slot
-            # (free slots drift harmlessly — admission resets both counters)
-            pg.host_pos += np.asarray(n_acc_h, np.int64) + 1
-
+    def _commit_accepted(self, g: _DepthGroup, active_ix: List[int],
+                         out_h: np.ndarray, n_acc_h: np.ndarray,
+                         now_s: float) -> int:
+        """Feed each active slot its accepted drafts plus the verify's own
+        token, handing out those past the prompt; retire finished requests
+        and roll back pages grown for rejected drafts. Returns the tokens
+        handed out."""
         rec_on = self._rec.enabled
-        rids = [g.slots[i].rid for i in active_ix] if rec_on else None
+        pg = g.paging
         produced = 0
         for i in active_ix:
             req = g.slots[i]
@@ -1859,17 +1903,13 @@ class ServingEngine:
             elif pg is not None:
                 # rollback: pages grown for rejected draft positions free
                 pg.trim(i)
-        self.spec_generated_tokens += produced
-        self._h_spec.observe(dt * 1e3)
-        if rec_on:
-            self._rec.launch(
-                site, t0, t0 + dt, depth=g.depth, rids=rids,
-                occupancy=len(active_ix), tokens=produced,
-                widths=[g.widths[i] for i in active_ix],
-                key=list(tree_verify_compile_key(g.depth, shape)
-                         if kind == "tree"
-                         else verify_compile_key(g.depth, shape)))
+        return produced
 
+    def _spec_feedback(self, g: _DepthGroup, plan, sel, depth_budget: int,
+                       n_acc: List[int], dt: float) -> None:
+        """Acceptance telemetry of one speculative launch, and the fallback
+        to plain stepping when acceptance collapses."""
+        kind, shape = sel
         # speculative tick wall time lives in the SPEC telemetry only: the
         # controller's per-mode p50 is the SLO policy's per-decode-step
         # estimate, and a 2-launch multi-token tick recorded there would
@@ -1880,13 +1920,13 @@ class ServingEngine:
                           tree=shape if kind == "tree" else None,
                           nodes=(tree_node_budget(shape) if kind == "tree"
                                  else shape)))
-        tel.record([int(n_acc_h[i]) for i in active_ix], len(active_ix), dt)
+        tel.record(n_acc, len(n_acc), dt)
         # window entries are PER-CANDIDATE acceptance: a tree's depth
         # fraction measures per-level survival (1-(1-a)^b) and must be
         # inverted so tree and linear launches feed the policy (and the
         # collapse threshold) one comparable number
         g.accept_window.append(per_candidate_accept_rate(
-            float(np.mean([n_acc_h[i] for i in active_ix])) / depth_budget,
+            float(np.mean(n_acc)) / depth_budget,
             shape if kind == "tree" else None))
         spec = self.speculative
         if (len(g.accept_window) == g.accept_window.maxlen
@@ -1899,128 +1939,56 @@ class ServingEngine:
                 rate=float(np.mean(g.accept_window)),
                 off_until=g.spec_off_until)
             g.accept_window.clear()
-        return dt
 
     def step(self, now_s: float = 0.0) -> float:
         """One engine tick. Returns device wall-time spent (seconds)."""
-        self._admit(now_s)
-        spent = 0.0
-        ticked = False
-        for g in self.groups.values():
-            active_ix = [i for i, r in enumerate(g.slots) if r is not None]
-            if not active_ix:
-                continue
-            ticked = True
-            sel = self._spec_select(g)
-            if sel is not None:
-                spent += self._spec_tick(g, sel, active_ix, now_s)
-                continue
-            if g.paging is not None:
-                spent += self._paged_tick(g, active_ix, now_s)
-                continue
-            self.executor.launch("decode")
-            toks = np.zeros((self.batch_size, 1), np.int32)
-            for i in active_ix:
-                toks[i, 0] = g.slots[i].next_input()
-            active = self._active_for(g.widths)
-            # telemetry attribution: the widest width in flight bounds this
-            # launch's active compute
-            w_max = max(g.widths[i] for i in active_ix)
-            mode = self._mode_by_dw[(g.depth, w_max)]
-            rec_on = self._rec.enabled
-            rids = [g.slots[i].rid for i in active_ix] if rec_on else None
-            t0 = self._clock() if rec_on else 0.0
-            logits, g.cache = self.ctrl.timed_step(
-                self.params, g.cache, self.executor.put(toks), active,
-                mode=mode, tokens=len(active_ix))
-            spent += self.ctrl.last_step_s
-            self._h_decode.observe(self.ctrl.last_step_s * 1e3)
-            self.decode_launches += 1
-            self.per_mode_launch_equiv += len(
-                {(g.depth, g.widths[i]) for i in active_ix})
-            if self.temperature > 0:
-                s_op = self.executor.put(np.uint32(self.step_count))
-                nxt = np.asarray(self._sample_fn(
-                    logits[:, 0], g.keys, self._temp_op, s_op))
-            else:
-                nxt = np.asarray(
-                    jnp.argmax(logits[:, 0, : self.cfg.vocab_size], axis=-1))
-            produced = 0
-            for i in active_ix:
-                req = g.slots[i]
-                req.fed += 1
-                # once the prompt is consumed, each step's argmax is a fresh
-                # generated token (the step that eats the last prompt token
-                # also yields the first one)
-                if req.fed >= len(req.prompt) and not req.done:
-                    req.generated.append(int(nxt[i]))
-                    produced += 1
-                    if rec_on and len(req.generated) == 1:
-                        self._rec.request_event(req.rid, "first_token")
-                if req.done:
-                    self._complete(g, i, req, now_s)
-            if rec_on:
-                self._rec.launch(
-                    "decode", t0, t0 + self.ctrl.last_step_s, depth=g.depth,
-                    rids=rids, occupancy=len(active_ix), tokens=produced,
-                    widths=[g.widths[i] for i in active_ix],
-                    key=["decode", g.depth])
-        self.ticks_with_work += ticked
-        self.step_count += 1
+        rec = self._rec
+        with rec.span("engine.step", tick=self.step_count, rows=self.n_active):
+            with rec.span("engine.admit") as sp:
+                sp.set(admitted=self._admit(now_s))
+            spent = 0.0
+            ticked = False
+            for g in self.groups.values():
+                active_ix = [i for i, r in enumerate(g.slots) if r is not None]
+                if not active_ix:
+                    continue
+                ticked = True
+                sel = self._spec_select(g)
+                if sel is not None:
+                    spent += self._spec_tick(g, sel, active_ix, now_s)
+                elif g.paging is not None:
+                    spent += self._paged_tick(g, active_ix, now_s)
+                else:
+                    spent += self._dense_tick(g, active_ix, now_s)
+            self.ticks_with_work += ticked
+            self.step_count += 1
         return spent
 
-    def _paged_tick(self, g: _DepthGroup, active_ix: List[int],
-                    now_s: float) -> float:
-        """One plain decode tick through the bucketed paged executable.
-
-        Host page bookkeeping first (grow each active slot's mapping to its
-        write position, CoW-copy any shared page in range), then ONE launch
-        of the ``("paged_decode", depth, bucket)`` executable — bucket is
-        the smallest compiled table width covering every active slot, so
-        variable-length slots re-trace nothing.
-        """
-        self.executor.launch("paged_decode")
-        pg = g.paging
-        needed = 1
-        for i in active_ix:
-            pos = int(pg.host_pos[i])
-            pg.ensure_slot(i, pos)
-            for src, dst in pg.cow_pairs(i, pos, pos):
-                g.cache = self._copy_page(g.cache,
-                                          self.executor.put(np.int32(src)),
-                                          self.executor.put(np.int32(dst)))
-            needed = max(needed, min(pos // pg.ps + 1, pg.cap_pages))
-        bucket = self._bucket_for(needed)
-        pages_op = self.executor.put(pg.table[:, :bucket].copy())
+    def _decode_inputs(self, g: _DepthGroup, active_ix: List[int]):
+        """The launch's next-token operand and per-slot width operands."""
         toks = np.zeros((self.batch_size, 1), np.int32)
         for i in active_ix:
             toks[i, 0] = g.slots[i].next_input()
-        active = self._active_for(g.widths)
-        w_max = max(g.widths[i] for i in active_ix)
-        mode = self._mode_by_dw[(g.depth, w_max)]
-        fn = self.ctrl.aux_step(paged_decode_compile_key(g.depth, bucket))
-        self.ctrl.stats["dispatches"] += 1
-        rec_on = self._rec.enabled
-        rids = [g.slots[i].rid for i in active_ix] if rec_on else None
-        t0 = self._clock()
-        logits, g.cache = fn(self.params, g.cache, self.executor.put(toks),
-                             active, pages_op)
-        jax.block_until_ready((logits, g.cache))
-        dt = self._clock() - t0
-        self.ctrl.telemetry[mode.name].record(dt, len(active_ix))
-        self.ctrl.last_step_s = dt
-        self._h_decode.observe(dt * 1e3)
-        pg.host_pos += 1  # mirror the device counter (ALL slots advance)
-        self.decode_launches += 1
-        self.per_mode_launch_equiv += len(
-            {(g.depth, g.widths[i]) for i in active_ix})
-        if self.temperature > 0:
-            s_op = self.executor.put(np.uint32(self.step_count))
-            nxt = np.asarray(self._sample_fn(
-                logits[:, 0], g.keys, self._temp_op, s_op))
-        else:
-            nxt = np.asarray(
+        return self.executor.put(toks), self._active_for(g.widths)
+
+    def _readback(self, g: _DepthGroup, logits) -> np.ndarray:
+        """Each slot's next token on the host: argmax, or the slot's sample
+        stream under sampled serving."""
+        with self._rec.span("engine.readback"):
+            if self.temperature > 0:
+                s_op = self.executor.put(np.uint32(self.step_count))
+                return np.asarray(self._sample_fn(
+                    logits[:, 0], g.keys, self._temp_op, s_op))
+            return np.asarray(
                 jnp.argmax(logits[:, 0, : self.cfg.vocab_size], axis=-1))
+
+    def _commit(self, g: _DepthGroup, active_ix: List[int], nxt: np.ndarray,
+                now_s: float) -> int:
+        """Feed one position per active slot and hand out its token once the
+        prompt is consumed (the step that eats the last prompt token also
+        yields the first one); retire finished requests. Returns the tokens
+        handed out."""
+        rec_on = self._rec.enabled
         produced = 0
         for i in active_ix:
             req = g.slots[i]
@@ -2032,12 +2000,99 @@ class ServingEngine:
                     self._rec.request_event(req.rid, "first_token")
             if req.done:
                 self._complete(g, i, req, now_s)
-        if rec_on:
-            self._rec.launch(
-                "paged_decode", t0, t0 + dt, depth=g.depth, rids=rids,
-                occupancy=len(active_ix), tokens=produced, bucket=bucket,
-                widths=[g.widths[i] for i in active_ix],
-                key=list(paged_decode_compile_key(g.depth, bucket)))
+        return produced
+
+    def _dense_tick(self, g: _DepthGroup, active_ix: List[int],
+                    now_s: float) -> float:
+        """One plain decode tick through the group's per-depth executable."""
+        rec = self._rec
+        # telemetry attribution: the widest width in flight bounds this
+        # launch's active compute
+        w_max = max(g.widths[i] for i in active_ix)
+        with rec.span("engine.decode", depth=g.depth, rows=len(active_ix),
+                      w_max=w_max):
+            self.executor.launch("decode")
+            with rec.span("engine.inputs"):
+                tok_op, active = self._decode_inputs(g, active_ix)
+            mode = self._mode_by_dw[(g.depth, w_max)]
+            rec_on = rec.enabled
+            rids = [g.slots[i].rid for i in active_ix] if rec_on else None
+            t0 = self._clock() if rec_on else 0.0
+            logits, g.cache = self.ctrl.timed_step(
+                self.params, g.cache, tok_op, active,
+                mode=mode, tokens=len(active_ix))
+            dt = self.ctrl.last_step_s
+            self._h_decode.observe(dt * 1e3)
+            self.decode_launches += 1
+            self.per_mode_launch_equiv += len(
+                {(g.depth, g.widths[i]) for i in active_ix})
+            nxt = self._readback(g, logits)
+            with rec.span("engine.commit"):
+                produced = self._commit(g, active_ix, nxt, now_s)
+                if rec_on:
+                    rec.launch(
+                        "decode", t0, t0 + dt, depth=g.depth, rids=rids,
+                        occupancy=len(active_ix), tokens=produced,
+                        widths=[g.widths[i] for i in active_ix],
+                        key=["decode", g.depth])
+        return dt
+
+    def _paged_tick(self, g: _DepthGroup, active_ix: List[int],
+                    now_s: float) -> float:
+        """One plain decode tick through the bucketed paged executable.
+
+        Host page bookkeeping first (grow each active slot's mapping to its
+        write position, CoW-copy any shared page in range), then ONE launch
+        of the ``("paged_decode", depth, bucket)`` executable — bucket is
+        the smallest compiled table width covering every active slot, so
+        variable-length slots re-trace nothing.
+        """
+        rec = self._rec
+        w_max = max(g.widths[i] for i in active_ix)
+        with rec.span("engine.paged_decode", depth=g.depth,
+                      rows=len(active_ix), w_max=w_max):
+            self.executor.launch("paged_decode")
+            pg = g.paging
+            with rec.span("engine.inputs"):
+                needed = 1
+                for i in active_ix:
+                    pos = int(pg.host_pos[i])
+                    pg.ensure_slot(i, pos)
+                    for src, dst in pg.cow_pairs(i, pos, pos):
+                        g.cache = self._copy_page(
+                            g.cache, self.executor.put(np.int32(src)),
+                            self.executor.put(np.int32(dst)))
+                    needed = max(needed, min(pos // pg.ps + 1, pg.cap_pages))
+                bucket = self._bucket_for(needed)
+                pages_op = self.executor.put(pg.table[:, :bucket].copy())
+                tok_op, active = self._decode_inputs(g, active_ix)
+            mode = self._mode_by_dw[(g.depth, w_max)]
+            fn = self.ctrl.aux_step(paged_decode_compile_key(g.depth, bucket))
+            self.ctrl.stats["dispatches"] += 1
+            rec_on = rec.enabled
+            rids = [g.slots[i].rid for i in active_ix] if rec_on else None
+            with rec.span("engine.device"):
+                t0 = self._clock()
+                logits, g.cache = fn(self.params, g.cache, tok_op, active,
+                                     pages_op)
+                jax.block_until_ready((logits, g.cache))
+                dt = self._clock() - t0
+            self.ctrl.telemetry[mode.name].record(dt, len(active_ix))
+            self.ctrl.last_step_s = dt
+            self._h_decode.observe(dt * 1e3)
+            pg.host_pos += 1  # mirror the device counter (ALL slots advance)
+            self.decode_launches += 1
+            self.per_mode_launch_equiv += len(
+                {(g.depth, g.widths[i]) for i in active_ix})
+            nxt = self._readback(g, logits)
+            with rec.span("engine.commit"):
+                produced = self._commit(g, active_ix, nxt, now_s)
+                if rec_on:
+                    rec.launch(
+                        "paged_decode", t0, t0 + dt, depth=g.depth, rids=rids,
+                        occupancy=len(active_ix), tokens=produced,
+                        bucket=bucket, widths=[g.widths[i] for i in active_ix],
+                        key=list(paged_decode_compile_key(g.depth, bucket)))
         return dt
 
     def _bucket_for(self, needed: int) -> int:

@@ -6,11 +6,15 @@ path, snapshot/restore carrying the full metrics state, SLO catch-up after
 failover, and the chaos-scenario acceptance check: a failover run under
 tracing exports Chrome trace JSON whose launch spans account for every
 committed token."""
+import gc
+import glob
 import json
+import os
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData, TraceAnnotation
 
 from repro.configs import smoke_config
 from repro.models.model import init_params
@@ -19,7 +23,8 @@ from repro.runtime.fault_tolerance import ExecutorSupervisor, FailurePlan
 from repro.runtime.observability import (DEFAULT_LATENCY_BUCKETS_MS,
                                          EventStream, Histogram,
                                          MetricsRegistry, Observability,
-                                         TraceRecorder, _TupleView)
+                                         TraceRecorder, _TupleView,
+                                         gc_pauses)
 from repro.runtime.serving import (Request, ServingEngine, SLOPolicy,
                                    poisson_trace)
 from repro.runtime.speculative import SpecConfig
@@ -194,6 +199,159 @@ def test_recorder_schema_and_cap():
     json.dumps(trace)
 
 
+def test_span_disabled_is_shared_noop():
+    """Recorder disabled and no profiler session: ``span`` hands back one
+    shared no-op whatever its name or args, and records nothing."""
+    assert not TraceAnnotation.is_enabled()
+    rec = TraceRecorder(enabled=False)
+    sp = rec.span("engine.step", tick=3, rows=2)
+    assert sp is rec.span("engine.decode") is TraceRecorder().span("x")
+    with sp as inner:
+        inner.set(admitted=1)
+    assert rec.events == [] and rec.dropped == 0
+
+
+def test_span_chrome_lane_nests_beside_launch_lane():
+    """Enabled: phase spans are complete events on tid 1 on the recorder's
+    clock, args set inside the span included; launches keep tid 0."""
+    clk = [1.0]
+    rec = TraceRecorder(enabled=True, clock=lambda: clk[0])
+    with rec.span("engine.step", tick=0, rows=1):
+        with rec.span("engine.admit") as sp:
+            clk[0] = 1.5
+            sp.set(admitted=2)
+        rec.launch("decode", 1.5, 2.0, tokens=1)
+        clk[0] = 2.5
+    admit, launch_b, _, step = rec.events
+    assert (admit["ph"], admit["tid"], admit["ts"], admit["dur"]) == \
+        ("X", 1, 1e6, 0.5e6)
+    assert admit["args"] == {"admitted": 2}
+    assert (step["name"], step["ts"], step["dur"]) == ("engine.step", 1e6, 1.5e6)
+    assert step["args"] == {"tick": 0, "rows": 1}
+    assert launch_b["ph"] == "B" and launch_b["tid"] == 0
+    json.dumps(rec.export_chrome_trace())
+
+
+def test_gc_hook_installed_once_and_observes_pauses():
+    hist = gc_pauses()
+    Observability()
+    Observability()
+    assert gc_pauses() is hist
+    assert sum(cb.__class__.__name__ == "_GcPauses" for cb in gc.callbacks) == 1
+    n = hist.count
+    gc.collect()
+    assert hist.count == n + 1 and hist.sum > 0
+    g = Observability().registry.to_json()["gauges"]
+    assert g["host_gc_pauses"] == hist.count
+    assert g["host_gc_pause_ms_sum"] == hist.sum
+
+
+# ---------------------------------------------------------------------------
+# engine phase spans in the JAX profiler's trace
+# ---------------------------------------------------------------------------
+
+DECODE_PARENTS = {"dense": "engine.decode", "paged": "engine.paged_decode",
+                  "spec": "engine.verify"}
+PHASES = ("engine.inputs", "engine.device", "engine.readback",
+          "engine.commit")
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the trace's events as
+    ``(name, start_ns, end_ns, stats)``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("engine.", "host.")):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def _inside(span, parents):
+    _, a, b, _ = span
+    return [p for p in parents if p[1] <= a and b <= p[2]]
+
+
+def _serve(eng, lengths, rid0):
+    for k, plen in enumerate(lengths):
+        eng.submit(Request(rid=rid0 + k, prompt=tuple(range(1, plen + 1)),
+                           max_new_tokens=3))
+    n = 0
+    while (eng.queue or eng.n_active) and n < 100:
+        eng.step()
+        n += 1
+    assert not (eng.queue or eng.n_active)
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged", "spec"])
+def test_engine_spans_nest_in_profiler_trace(kind, tmp_path):
+    """A few CPU ticks under the profiler: every decode and prefill lies in
+    an ``engine.step``, the four launch phases in their decode span, args
+    present; ``engine_program_builds`` counts warm-up's misses, stays flat
+    while serving warmed shapes, and names the one new prompt length."""
+    eng = _factory(paged=PagedLayout(page_size=4) if kind == "paged" else None,
+                   speculative=SpecConfig(ks=(2,)) if kind == "spec"
+                   else None)()
+    builds = eng.metrics.counters["engine_program_builds"]
+    lengths = (5, 7, 5)
+    _serve(eng, lengths, 0)  # warms the prefills of these lengths
+    warm = builds.value
+    assert warm >= len(eng.groups) + len(set(lengths))
+    spans = _profiled(tmp_path, lambda: _serve(eng, lengths, 10))
+    assert builds.value == warm
+    assert not [s for s in spans if s[0] == "engine.build"]
+
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    steps = by["engine.step"]
+    parent = DECODE_PARENTS[kind]
+    decodes = by[parent]
+    assert decodes and by["engine.prefill"] and by["engine.admit"]
+    for s in decodes + by["engine.prefill"] + by["engine.admit"]:
+        assert len(_inside(s, steps)) == 1, s
+    for _, _, _, st in steps:
+        assert {"tick", "rows"} <= set(st)
+    assert any(st["admitted"] >= 1 for *_, st in by["engine.admit"])
+    for _, _, _, st in by["engine.prefill"]:
+        assert st["plen"] in lengths and st["depth"] in eng.groups
+    for _, _, _, st in decodes:
+        assert st["depth"] in eng.groups and st["rows"] >= 1
+    if kind == "spec":
+        assert len(by["engine.draft"]) == len(decodes)
+        for s in by["engine.draft"]:
+            assert len(_inside(s, steps)) == 1
+    else:
+        for _, _, _, st in decodes:
+            assert 0 < st["w_max"] <= 1.0
+    # each decode span holds its launch's phases, one each (the spec draft
+    # span holds its own inputs and device phases)
+    inner = decodes + by.get("engine.draft", [])
+    for name in PHASES:
+        assert all(len(_inside(s, inner)) == 1 for s in by[name]), name
+    for d in decodes:
+        kids = [s[0] for s in spans if _inside(s, [d]) and s is not d]
+        want = PHASES[1:] if kind == "spec" else PHASES
+        assert sorted(kids) == sorted(want), kids
+
+    spans = _profiled(tmp_path / "new", lambda: _serve(eng, (9,), 20))
+    new = [st["key"] for name, *_, st in spans if name == "engine.build"]
+    assert builds.value == warm + 1
+    assert new == [str((9, eng.admission_mode.depth))]
+
+
 # ---------------------------------------------------------------------------
 # engine integration
 # ---------------------------------------------------------------------------
@@ -291,13 +449,16 @@ def test_chaos_trace_accounts_every_committed_token(traced_chaos):
 
 def test_chaos_metrics_match_fault_free(traced_chaos):
     """Post-recovery registry counters land exactly on the fault-free run's
-    (timing-valued counters excluded): snapshot/restore carries metrics and
-    the redone tick re-earns its increments."""
+    (timing-valued counters excluded, and ``engine_program_builds``, which
+    counts each engine's own cache misses: both ping-pong engines warm up
+    into the shared registry, and a standby replays through its own
+    caches): snapshot/restore carries metrics and the redone tick re-earns
+    its increments."""
     obs, sup, ref_eng = traced_chaos
 
     def deterministic(eng):
         out = {k: v for k, v in eng.export_metrics()["counters"].items()
-               if k != "engine_prefill_s"}
+               if k not in ("engine_prefill_s", "engine_program_builds")}
         return out
 
     assert deterministic(sup.engine) == deterministic(ref_eng)
