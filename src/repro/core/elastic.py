@@ -198,8 +198,8 @@ def active_widths_batch(cfg: ModelConfig, widths) -> Dict[str, jnp.ndarray]:
     """Per-slot active dims: one (B,) int32 vector per gated dimension.
 
     ``widths`` is a sequence of width fractions, one per batch slot — slots
-    of *different* widths share a single decode launch (the kernel reads each
-    row's active widths from scalar prefetch)."""
+    of *different* widths share a single decode launch (the kernel masks each
+    row at its own active widths)."""
     per = [active_widths(cfg, w) for w in widths]
     return {k: jnp.asarray([p[k] for p in per], jnp.int32) for k in per[0]}
 

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.morph_matmul import BLOCK as _MM_BLOCK
 from repro.kernels.morph_matmul import morph_matmul as _morph_matmul
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
@@ -21,7 +22,7 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def morph_matmul(x, w, active_n=None, active_k=None, *, block=(128, 128, 128),
+def morph_matmul(x, w, active_n=None, active_k=None, *, block=_MM_BLOCK,
                  interpret: Optional[bool] = None, impl: str = "pallas"):
     itp = default_interpret() if interpret is None else interpret
     return _morph_matmul(x, w, active_n, active_k, block=block, interpret=itp,

@@ -13,7 +13,7 @@ def morph_matmul_ref(x, w, active_n=None, active_k=None):
 
     ``active_n`` / ``active_k`` may be per-batch sequences (len B) when x is
     (B, M, K) — each batch row is sliced at its own active widths, mirroring
-    the kernel's per-batch scalar prefetch."""
+    the kernel's per-row width masks."""
     K = x.shape[-1]
     N = w.shape[-1]
 

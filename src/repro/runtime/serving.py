@@ -19,8 +19,8 @@ the TPU analogue of that story end-to-end:
   executable and one slot group with one full-width cache. Width does NOT
   fragment slots: every slot carries its own width fraction, lowered each
   tick to per-slot active-dim vectors (``elastic.active_widths_batch``) that
-  ``kernels.morph_matmul`` reads from scalar prefetch — out-of-width tiles
-  issue no MXU work. A tick with three widths in flight at one depth issues
+  ``kernels.morph_matmul`` masks row by row — tiles beyond every row's
+  width issue no MXU work. A tick with three widths in flight at one depth issues
   ONE decode launch, not three; warmup compiles ``len(depths)`` executables,
   not ``len(modes)``. A mode switch still only applies to *newly admitted*
   requests — in-flight slots keep the width they started with.

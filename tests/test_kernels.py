@@ -110,22 +110,91 @@ def test_morph_matmul_bf16_non_aligned_active(impl, active_n, active_k):
     assert np.all(np.asarray(y, np.float32)[:, active_n:] == 0.0)
 
 
-@pytest.mark.parametrize("impl", ["pallas", "ref"])
-def test_morph_matmul_batched_per_batch_active(impl):
-    """The 3D grid: each batch row at its OWN (non-tile-aligned) active
-    widths, in one launch — the mixed-width serving batch."""
+# (B, M, K, N, block, dtype, per-row active_n, per-row active_k)
+_WIDTH_MIX = (3, 32, 64, 96, (32, 32, 32), jnp.bfloat16, [96, 50, 16], [64, 33, 64])
+_ROWS = np.random.default_rng(10)
+_ROW_CASES = {
+    # a decode batch: 16 rows, one row block, a width of its own per row
+    "b16m1": (16, 1, 256, 192, (256, 128, 64), jnp.bfloat16,
+              [192, 0, 1, 64, 65, 100, 191, 192] + list(_ROWS.integers(0, 193, 8)),
+              [256, 1, 0, 128, 129, 200, 255, 256] + list(_ROWS.integers(0, 257, 8))),
+    # a verify batch (B slots x K+1 positions): 20 rows, not a multiple of
+    # the sublane tile, padded to one block of 32
+    "b4m5": (4, 5, 128, 160, (256, 64, 64), jnp.bfloat16,
+             [160, 33, 96, 1], [100, 128, 7, 64]),
+    "b4m5_f32": (4, 5, 96, 96, (256, 32, 32), jnp.float32,
+                 [96, 40, 0, 77], [96, 31, 96, 12]),
+    # every row narrow: whole N tiles and K steps skip
+    "narrow": (8, 2, 128, 128, (256, 32, 32), jnp.bfloat16,
+               [40, 33, 8, 1, 0, 32, 17, 40], [50, 64, 1, 33, 9, 49, 0, 2]),
+    # 35 rows over a 16-row tile: three row blocks straddling batch rows
+    "rowblocks": (5, 7, 64, 96, (16, 32, 32), jnp.bfloat16,
+                  [96, 50, 16, 0, 95], [64, 33, 64, 1, 40]),
+}
+
+
+@pytest.mark.parametrize("impl,case", [
+    pytest.param(impl, _WIDTH_MIX, id=impl) for impl in ("pallas", "ref")
+] + [
+    pytest.param(impl, c, id=f"{impl}-{name}")
+    for name, c in _ROW_CASES.items() for impl in ("pallas", "ref")
+])
+def test_morph_matmul_batched_per_batch_active(impl, case):
+    """Each batch row at its OWN (non-tile-aligned) active widths, in one
+    launch — the mixed-width serving batch — whether its rows share one
+    row block or spread over several."""
+    B, M, K, N, block, dtype, ans, aks = case
     kx, kw = jax.random.split(jax.random.PRNGKey(8))
-    x = jax.random.normal(kx, (3, 32, 64), jnp.bfloat16)
-    w = jax.random.normal(kw, (64, 96), jnp.bfloat16)
-    ans, aks = [96, 50, 16], [64, 33, 64]
+    x = jax.random.normal(kx, (B, M, K), dtype)
+    w = jax.random.normal(kw, (K, N), dtype)
+    ans, aks = [int(a) for a in ans], [int(a) for a in aks]
     y = morph_matmul(x, w, jnp.array(ans, jnp.int32), jnp.array(aks, jnp.int32),
-                     block=(32, 32, 32), interpret=True, impl=impl)
+                     block=block, interpret=True, impl=impl)
     yr = ref.morph_matmul_ref(x, w, ans, aks)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32),
-                               atol=_tol(jnp.bfloat16) * 64 ** 0.5, rtol=2e-2)
+                               atol=_tol(dtype) * K ** 0.5, rtol=2e-2)
     for b, an in enumerate(ans):
         assert np.all(np.asarray(y, np.float32)[b, :, an:] == 0.0)
+
+
+def _pallas_grids(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["grid_mapping"]
+        for v in eqn.params.values():
+            if hasattr(v, "jaxpr"):
+                yield from _pallas_grids(v.jaxpr)
+            elif hasattr(v, "eqns"):
+                yield from _pallas_grids(v)
+
+
+def test_morph_matmul_decode_fetches_each_weight_tile_once():
+    """A decode batch of 16 rows is ONE row block: the grid is (1, N/bn,
+    K/bk) with no batch axis, so each weight tile is visited once per launch
+    (not once per batch row); widths switch without a trace."""
+    from repro.kernels.morph_matmul import trace_count
+
+    B, K, N = 16, 1024, 768  # N: a width no other test compiles
+    kx, kw = jax.random.split(jax.random.PRNGKey(11))
+    x = jax.random.normal(kx, (B, 1, K), jnp.bfloat16)
+    w = jax.random.normal(kw, (K, N), jnp.bfloat16)
+    t0 = trace_count()
+    for an in ([N] * B, [N, N // 2] * (B // 2), [1] * B):
+        an = jnp.array(an, jnp.int32)
+        y = morph_matmul(x, w, an, None, interpret=True)
+        yr = ref.morph_matmul_ref(x, w, an, None)
+        np.testing.assert_allclose(np.asarray(y, np.float32),
+                                   np.asarray(yr, np.float32),
+                                   atol=_tol(jnp.bfloat16) * K ** 0.5, rtol=2e-2)
+    assert trace_count() - t0 == 1, "one trace per logical shape"
+
+    jaxpr = jax.make_jaxpr(lambda x, w, a: morph_matmul(
+        x, w, a, None, interpret=True))(x, w, jnp.full((B,), N, jnp.int32))
+    (gm,) = list(_pallas_grids(jaxpr.jaxpr))
+    w_block = tuple(b.block_size for b in gm.block_mappings[3].block_shape)
+    assert gm.grid == (1, N // w_block[1], K // w_block[0]), gm.grid
+    assert w_block == (512, 384), w_block  # 384: the widest 128-multiple dividing 768
 
 
 def test_morph_matmul_pad_path_traces_once():

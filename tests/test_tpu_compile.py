@@ -73,16 +73,24 @@ def _attn_operands(one_chip, cache_shape):
     return params, cache, _sds(one_chip, (B,), "int32"), active
 
 
-@pytest.mark.parametrize("b,m,k,n", [
-    (B, 1, CFG.d_model, CFG.q_dim),    # decode: wq / wo
-    (B, 1, CFG.d_model, CFG.kv_dim),   # decode: wk / wv
-    (1, 256, CFG.d_model, CFG.q_dim),  # a 256-token prompt
-], ids=["decode_q", "decode_kv", "prefill_256"])
-def test_morph_matmul_compiles_for_v5e(one_chip, b, m, k, n):
+@pytest.mark.parametrize("b,m,k,n,gate", [
+    (B, 1, CFG.d_model, CFG.q_dim, "n"),    # decode: wq / wo
+    (B, 1, CFG.d_model, CFG.kv_dim, "n"),   # decode: wk / wv
+    (1, 256, CFG.d_model, CFG.q_dim, "n"),  # a 256-token prompt
+    # the chat cell's 16-slot decode batch, each row at its own width
+    (16, 1, CFG.d_model, CFG.q_dim, "n"),   # wq
+    (16, 1, CFG.d_model, CFG.kv_dim, "n"),  # wk / wv
+    (16, 1, CFG.q_dim, CFG.d_model, "k"),   # wo, gated on its input rows
+], ids=["decode_q", "decode_kv", "prefill_256", "b16_q", "b16_kv", "b16_o"])
+def test_morph_matmul_compiles_for_v5e(one_chip, b, m, k, n, gate):
+    def fn(x, w, a):
+        if gate == "n":
+            return morph_matmul(x, w, a, None, impl="pallas")
+        return morph_matmul(x, w, None, a, impl="pallas")
+
     _assert_mosaic(
-        lambda x, w, a: morph_matmul(x, w, a, None, impl="pallas"),
-        _sds(one_chip, (b, m, k), CFG.dtype), _sds(one_chip, (k, n), CFG.dtype),
-        _sds(one_chip, (b,), "int32"))
+        fn, _sds(one_chip, (b, m, k), CFG.dtype),
+        _sds(one_chip, (k, n), CFG.dtype), _sds(one_chip, (b,), "int32"))
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
