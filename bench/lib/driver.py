@@ -1,8 +1,9 @@
 """Drive ``ServingEngine`` with a traffic mix on the host's wall clock.
 
 The window calls the engine's public entry points only: ``submit``,
-``set_admission_mode`` and ``step`` on the default ``LocalExecutor``
-(dense cache, unfused, greedy, no speculation). Arrivals are due on the
+``set_admission_mode`` and ``step`` (dense cache, unfused, greedy, no
+speculation), on the default ``LocalExecutor`` for a cell on one chip and
+on a ``MeshExecutor`` for one on several. Arrivals are due on the
 wall clock whether or not the engine keeps up (open loop), or an offline
 backlog is kept topped up. After every tick the driver reads the requests
 it submitted to see which tokens appeared, so every token gets the time
@@ -78,6 +79,7 @@ class Window:
     trace: Optional[object] = None  # bench.lib.trace.TraceSummary
     peaks: Dict = field(default_factory=dict)
     setup_s: float = 0.0
+    chips: int = 1  # the cell's chips: one SPMD program runs on each
     t_end: float = 0.0  # when the run stopped serving
     t_trace: Optional[float] = None  # when the profiler started, if it did
 

@@ -25,7 +25,8 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from bench.lib.trace import DECODE_MODULE, _events, _gaps, _stats, find_xplane
+from bench.lib.trace import (DECODE_MODULE, _events, _gaps, _stats,
+                              device_planes, find_xplane, is_collective)
 
 # the spans a launch's time splits into, and the phases of an engine tick
 # that hold no other span
@@ -45,8 +46,8 @@ class Span:
 class EngineTrace:
     """The traced window ``[lo, hi)`` (ns): its host spans (the driver's
     ``bench.*``, the engine's ``engine.*``, ``host.gc``) sorted by start,
-    the ``bench.tick`` spans of its ticks, and the device's module
-    executions and operation intervals."""
+    the ``bench.tick`` spans of its ticks, and chip 0's module executions,
+    operation intervals and collective operations' intervals."""
 
     lo: float
     hi: float
@@ -54,6 +55,7 @@ class EngineTrace:
     ticks: List[Span]
     modules: List[Tuple[float, float, str]] = field(default_factory=list)
     ops: List[Tuple[float, float]] = field(default_factory=list)
+    collectives: List[Tuple[float, float]] = field(default_factory=list)
 
     def engine(self, name: str) -> List[Span]:
         """The ``name`` spans inside a tick of the window."""
@@ -100,21 +102,21 @@ def read_xplane(path: str, win) -> Optional[EngineTrace]:
     offsets.sort()
     off = offsets[len(offsets) // 2]  # the driver's clock to the trace's
     lo, hi = (tr.t0 + off) * 1e9, (tr.t1 + off) * 1e9
-    modules, ops = [], []
-    devices = [p for p in pd.planes if p.name.startswith("/device:")
-               and "TPU" in p.name]
-    for plane in devices[:1]:
+    modules, ops, collectives = [], [], []
+    for plane in device_planes(pd):
         for line in plane.lines:
             if line.name == "XLA Modules":
                 modules = [(s, s + d, n) for n, s, d, _ in _events(line)]
             elif line.name == "XLA Ops":
                 ops = [(s, s + d) for _, s, d, _ in _events(line)]
+                collectives = [(s, s + d) for n, s, d, _ in _events(line)
+                               if is_collective(n)]
     return EngineTrace(
         lo=lo, hi=hi,
         spans=sorted((s for s in spans if s.t1 > lo and s.t0 < hi),
                      key=lambda s: (s.t0, -s.t1)),
         ticks=sorted(kept, key=lambda s: s.t0), modules=sorted(modules),
-        ops=ops)
+        ops=ops, collectives=sorted(collectives))
 
 
 def _decode_modules(et: EngineTrace) -> List[Tuple[float, float, str]]:
@@ -150,19 +152,22 @@ def clock_lag_ns(et: EngineTrace) -> float:
     return min(bounds) if bounds else 0.0
 
 
+def _paired(et: EngineTrace, lag: float):
+    """Each ``engine.decode`` span with the decode module executions that
+    start inside it, shifted by ``lag`` onto the host's clock."""
+    mods = [(a + lag, b + lag, n) for a, b, n in _decode_modules(et)]
+    starts = [m[0] for m in mods]
+    return [(s, mods[bisect.bisect_left(starts, s.t0):
+                     bisect.bisect_left(starts, s.t1)])
+            for s in et.engine("engine.decode")]
+
+
 def decode_launches(et: EngineTrace) -> List[Tuple[Span, float, int]]:
     """Each ``engine.decode`` span with the device time (ns) and number of
     the decode module executions that start inside it, on the host's
     clock."""
-    lag = clock_lag_ns(et)
-    mods = [(a + lag, b + lag, n) for a, b, n in _decode_modules(et)]
-    starts = [m[0] for m in mods]
-    out = []
-    for s in et.engine("engine.decode"):
-        hit = mods[bisect.bisect_left(starts, s.t0):
-                   bisect.bisect_left(starts, s.t1)]
-        out.append((s, sum(b - a for a, b, _ in hit), len(hit)))
-    return out
+    return [(s, sum(b - a for a, b, _ in hit), len(hit))
+            for s, hit in _paired(et, clock_lag_ns(et))]
 
 
 def decode_device_ms(et: Optional[EngineTrace]) -> Optional[float]:
@@ -186,6 +191,25 @@ def decode_host_ms(et: Optional[EngineTrace]) -> Optional[float]:
     launches = decode_launches(et)
     return (sum(s.t1 - s.t0 - dev for s, dev, _ in launches) / len(launches)
             * 1e-6 if launches else None)
+
+
+def decode_collective_ms(et: Optional[EngineTrace]) -> Optional[float]:
+    """Mean over decode launches of the device time of the collective
+    operations that start inside their decode modules, on chip 0 (every
+    chip runs the same SPMD module). ``None`` where chip 0 ran no
+    collective: a cell on one chip has none to read."""
+    if et is None or not et.collectives:
+        return None
+    lag = clock_lag_ns(et)
+    coll = [(a + lag, b + lag) for a, b in et.collectives]
+    starts = [c[0] for c in coll]
+    per_launch = [
+        sum(d - c for a, b, _ in hit
+            for c, d in coll[bisect.bisect_left(starts, a):
+                             bisect.bisect_left(starts, b)])
+        for _, hit in _paired(et, lag)]
+    return (sum(per_launch) / len(per_launch) * 1e-6 if per_launch
+            else None)
 
 
 def prefill_stall_ms(et: Optional[EngineTrace]) -> Optional[float]:

@@ -50,15 +50,17 @@ def _traced_launches(win):
 
 def decode_mfu(win) -> Optional[float]:
     """Model FLOPs of the decode launches whose ticks began in the traced
-    window, over the device time of the decode programs there at the chip's
-    peak bf16 rate, in %."""
+    window, over the device time of the decode programs there (one chip's:
+    every chip of the cell runs each launch) at the peak bf16 rate of the
+    cell's chips, in %."""
     tr = win.trace
     if tr is None or not tr.decode_s:
         return None
     c = win.config
     fl = sum(F.decode_token_flops(c, w, l.depth, ctx)
              for l in _traced_launches(win) for w, ctx in l.rows)
-    return 100.0 * fl / (tr.decode_s * win.peaks["bf16_flops_per_s"])
+    return 100.0 * fl / (tr.decode_s * win.chips
+                         * win.peaks["bf16_flops_per_s"])
 
 
 def device_idle(win) -> Optional[float]:

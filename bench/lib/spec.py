@@ -182,15 +182,25 @@ def fold_multipliers(params, config: Dict):
     return p
 
 
-def make_weights(config: Dict, cfg, key):
+def make_weights(config: Dict, cfg, key, mesh=None):
     """The program's weights for a configuration, on the device in one
     jitted call: its initializer from ``key``, then the multipliers
-    folded in."""
+    folded in. With a serving ``mesh`` the call makes every array sharded
+    as the mesh's executor places it (``param_specs`` under
+    ``serve_policy``); jit's values do not depend on the output sharding,
+    so they are the one-device weights array for array."""
     import jax
 
     from repro.models.model import init_params
 
-    return jax.jit(lambda k: fold_multipliers(init_params(k, cfg), config))(key)
+    make = lambda k: fold_multipliers(init_params(k, cfg), config)  # noqa: E731
+    if mesh is None:
+        return jax.jit(make)(key)
+    from repro.parallel import sharding as SH
+
+    specs = SH.param_specs(jax.eval_shape(make, key), cfg, mesh,
+                           SH.serve_policy(cfg, mesh.shape["model"]))
+    return jax.jit(make, out_shardings=SH.shardings_for(specs, mesh))(key)
 
 
 def mode_table(config: Dict, traffic: Dict):

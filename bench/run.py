@@ -72,6 +72,13 @@ def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
+def fullest(peaks):
+    """The peak of the fullest device (``None`` where no device reports
+    one)."""
+    known = [p for p in peaks if p is not None]
+    return max(known) if known else None
+
+
 def _metric_values(readers, window, metrics):
     out = {}
     for m in metrics:
@@ -114,6 +121,15 @@ class Served:
         if cache_dir:  # every program, so that set-up loads and never compiles
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         self.jax, self.dev, self.devices = jax, dev, devices
+        # a cell on several chips serves on a 1 x chips tensor-parallel mesh
+        self.mesh = mesh = None
+        engine_kw = {}
+        if cell.chips > 1:
+            from repro.launch.mesh import make_serve_mesh
+            from repro.runtime.serving import MeshExecutor
+
+            self.mesh = mesh = make_serve_mesh(1, cell.chips)
+            engine_kw["executor"] = MeshExecutor(mesh)
         self.cell, self.seed = cell, seed
         self.conf, self.mix = conf, mix = cell.config, cell.traffic
         self.ref = S.reference_module(conf)
@@ -126,9 +142,10 @@ class Served:
         log(f"{cell.name}: {cfg.name} x {mix['engine']['batch_size']} slots, "
             f"cache {mix['engine']['cache_capacity']}, modes "
             f"{sorted({m.name for m in self.modes})}; device "
-            f"{dev.device_kind!r} x {len(devices)}; compile cache {cache_dir}")
+            f"{dev.device_kind!r} x {len(devices)}; mesh "
+            f"{dict(mesh.shape) if mesh else None}; compile cache {cache_dir}")
         t0 = time.perf_counter()
-        self.params = S.make_weights(conf, cfg, self.ref.root_key(seed))
+        self.params = S.make_weights(conf, cfg, self.ref.root_key(seed), mesh)
         jax.block_until_ready(self.params)
         self.t_init = time.perf_counter() - t0
         obs = Observability()
@@ -138,7 +155,7 @@ class Served:
             cache_capacity=mix["engine"]["cache_capacity"],
             modes=tuple(dict.fromkeys(sorted(self.modes,
                                              key=lambda m: (m.depth, m.width)))),
-            observability=obs)
+            observability=obs, **engine_kw)
         annotate = ((lambda name, **kw: jax.profiler.TraceAnnotation(name, **kw))
                     if trace else None)
         self.driver = D.Driver(self.engine, self.traffic, self.modes,
@@ -176,20 +193,22 @@ def run_cell(cell: S.Cell, *, seed: int, seconds: float, trace: bool,
         f"{sv.t_warm:.3f} s ({sv.n_warm} requests)")
     win = sv.driver.run(seconds, sv.request_cls, compiles,
                         tracer=Tracer(jax, min(seconds, TRACE_S)) if trace else None)
-    stats = dev.memory_stats() or {}
+    stats = [d.memory_stats() or {} for d in devices[:cell.chips]]
+    peaks = [s.get("peak_bytes_in_use") for s in stats]
     win.setup_s = setup_s
     win.config = conf
     win.peaks = sv.peaks
+    win.chips = cell.chips
     win.device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": len(devices),
-                  "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+                  "memory_peak_bytes": fullest(peaks),
+                  "memory_peak_bytes_per_device": peaks}
     late = [r.submit_t - r.due_t for r in win.recs if r.in_window]
     log(f"window: {len(win.ticks)} ticks, {win.counters['decode_launches']} "
         f"decode launches, {win.counters['prefills']} prefills, "
         f"{win.compiles} compilations inside the window; generator late by "
         f"max {max(late, default=0) * 1e3:.3f} ms over {len(late)} requests; "
-        f"peak {stats.get('peak_bytes_in_use')} of {stats.get('bytes_limit')} "
-        f"bytes")
+        f"peak {peaks} of {[s.get('bytes_limit') for s in stats]} bytes")
     if win.compiles:
         log(f"FAIL: {win.compiles} compilations inside the measured window")
     if trace:
@@ -197,6 +216,8 @@ def run_cell(cell: S.Cell, *, seed: int, seconds: float, trace: bool,
         win.trace = reduce_trace(TRACE_DIR, win)
         win.device["busy_s"] = win.trace.busy_s
         win.device["window_s"] = win.trace.window_s
+        log(f"traced {win.trace.window_s:.3f} s: busy per device "
+            f"{win.trace.busy_per_device_s} s")
     metrics = _metric_values(readers, win, cell.per_layer if trace
                              else cell.end_to_end)
     # a request is attempted once due in the window (open loop) or, from a

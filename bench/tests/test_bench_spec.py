@@ -69,7 +69,7 @@ def _take(tr, n):
     return [next(it) for _ in range(n)]
 
 
-@pytest.mark.parametrize("mix", ["chat", "batch", "longprompt"])
+@pytest.mark.parametrize("mix", ["chat", "chat-tp4", "batch", "longprompt"])
 def test_traffic_deterministic_and_same_work_for_every_seed(mix):
     t = S.load_traffic(mix)
     a = Traffic(t, seed=2**31 + 11, seconds=32, vocab=1000)

@@ -54,10 +54,12 @@ def tiny_traffic(name: str) -> dict:
     return t
 
 
-def tiny_cell(config: str, traffic: str, *, f32: bool = True) -> S.Cell:
-    """A configuration under a mix at test size, with the metrics of the
-    benchmark's cell of that pair or, for a pair not (yet) a cell, every
-    metric whose reader the mix's arrivals can feed."""
+def tiny_cell(config: str, traffic: str, *, f32: bool = True,
+              chips: int = 0) -> S.Cell:
+    """A configuration under a mix at test size, with the chips and metrics
+    of the benchmark's cell of that pair or, for a pair not (yet) a cell,
+    one chip and every metric whose reader the mix's arrivals can feed;
+    ``chips`` overrides the count."""
     bm = S.load_benchmark()
     cell = next((w for w in bm["workloads"]
                  if w["config"] == config and w["traffic"] == traffic), None)
@@ -65,8 +67,9 @@ def tiny_cell(config: str, traffic: str, *, f32: bool = True) -> S.Cell:
     if cell is not None:
         full = S.load_cell(cell["name"])
         name, e2e, per_layer = full.name, full.end_to_end, full.per_layer
+        cell_chips = full.chips
     else:
-        name = f"{config}.{traffic}"
+        name, cell_chips = f"{config}.{traffic}", 1
         e2e = [m for m in bm["end_to_end"] if m["name"] == "setup_s"] + [
             {"name": n, "unit": u} for n, u in (
                 (("ttft_p90_ms", "ms"), ("itl_p99_ms", "ms"))
@@ -74,4 +77,5 @@ def tiny_cell(config: str, traffic: str, *, f32: bool = True) -> S.Cell:
                 else (("output_tokens_per_s", "tokens/s"),))]
         per_layer = []
     return S.Cell(name=name, config=tiny_config(config, f32=f32),
-                  traffic=mix, chips=1, end_to_end=e2e, per_layer=per_layer)
+                  traffic=mix, chips=chips or cell_chips,
+                  end_to_end=e2e, per_layer=per_layer)
