@@ -41,7 +41,7 @@ class Rec:
     submit_t: float
     in_window: bool
     admit_t: Optional[float] = None  # start of the tick that admitted it
-    depth: int = 0
+    depth: int = 0  # in layers
     width: float = 1.0
     token_t: List[float] = field(default_factory=list)
 
@@ -51,7 +51,7 @@ class Launch:
     """One decode launch: its depth group and occupied rows."""
 
     tick: int
-    depth: int
+    depth: int  # in layers
     rows: List[Tuple[float, int]]  # (width, keys in attention) per request
 
 
@@ -98,10 +98,11 @@ COUNTERS = ("prefills", "prefill_s", "prefill_prompt_tokens",
             "decode_launches")
 
 
-def parse_mode(name: str) -> Tuple[int, float]:
-    """(depth, width) of a mode name ``d<depth>w<percent>``."""
+def parse_mode(name: str, period: int) -> Tuple[int, float]:
+    """(depth in layers, width) of a mode name ``d<depth>w<percent>``, whose
+    depth counts the engine's groups of ``period`` layers."""
     d, w = name[1:].split("w")
-    return int(d), int(w) / 100.0
+    return int(d) * period, int(w) / 100.0
 
 
 class CompileCounter:
@@ -229,7 +230,8 @@ class Driver:
                     r = rec.req
                     if rec.admit_t is None and r.status != "queued":
                         rec.admit_t = t0
-                        rec.depth, rec.width = parse_mode(r.mode_name)
+                        rec.depth, rec.width = parse_mode(r.mode_name,
+                                                          eng.cfg.period)
                     new = len(r.generated) - len(rec.token_t)
                     if new:
                         first = not rec.token_t

@@ -8,7 +8,7 @@ import importlib.util
 import json
 import os
 from dataclasses import dataclass
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Tuple
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -75,30 +75,176 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
 # ---------------------------------------------------------------------------
 
 
+# a layer kind as a configuration file names it (``layer_types``) -> as the
+# program's ``layer_pattern`` does
+LAYER_KINDS = {"attention": "attn", "mamba": "ssm"}
+# the file's ``hidden_act`` -> the program's ``activation``: HF's ``silu``
+# MLPs are gated (SwiGLU), its ``gelu`` and ``relu2`` ones are not
+ACTIVATIONS = {"silu": "swiglu", "gelu": "gelu", "relu2": "squared_relu"}
+POSITIONS = {"rope": True, "nope": False}
+
+
+def layer_types(config: Dict) -> List[str]:
+    """The program's kind of every layer the file runs: ``layer_types``
+    (which may list the published depth, longer than the file's) repeated
+    over ``num_hidden_layers``; a file without it is all attention."""
+    types = config.get("layer_types") or ["attention"]
+    bad = sorted(set(types) - set(LAYER_KINDS))
+    if bad:
+        raise ValueError(f"{config['name']}.json: layer_types names {bad}; "
+                         f"the harness knows {sorted(LAYER_KINDS)}")
+    return [LAYER_KINDS[types[i % len(types)]]
+            for i in range(config["num_hidden_layers"])]
+
+
+def period(config: Dict) -> int:
+    """Layers in one period of the file's ``layer_types``: the shortest
+    prefix that the whole list repeats (1 without the key). The program
+    scans one period as a group, so its depths count periods."""
+    types = config.get("layer_types") or ["attention"]
+    return next(p for p in range(1, len(types) + 1)
+                if types == types[:p] * (len(types) // p))
+
+
 def depth_of(config: Dict, label) -> int:
-    """A mix names depths by role: ``"full"`` or ``"exitN"`` (the N-th exit
-    group of the configuration); a number is taken as it is."""
+    """A depth in layers. A mix names depths by role: ``"full"`` (every
+    layer of the file) or ``"exitN"`` (the N-th exit group of the
+    configuration, counted in periods); a number is taken as layers."""
     if isinstance(label, int):
         return label
     if label == "full":
         return config["num_hidden_layers"]
     if label.startswith("exit"):
-        return config["serving"]["exit_groups"][int(label[4:])]
+        return config["serving"]["exit_groups"][int(label[4:])] * period(config)
     raise ValueError(f"unknown depth label {label!r}")
+
+
+def engine_depth(config: Dict, layers: int) -> int:
+    """The engine's depth for ``layers`` layers: whole groups (periods)."""
+    p = period(config)
+    if layers % p:
+        raise ValueError(f"{config['name']}.json: a depth of {layers} layers "
+                         f"is not a whole number of {p}-layer groups")
+    return layers // p
+
+
+def _pattern(config: Dict):
+    """``layer_types`` -> the program's ``layer_pattern``: one period."""
+    p = period(config)
+    if config["num_hidden_layers"] % p:
+        raise ValueError(f"{config['name']}.json: num_hidden_layers "
+                         f"{config['num_hidden_layers']} is not a whole number "
+                         f"of layer_types' {p}-layer periods")
+    return tuple(layer_types(config)[:p])
+
+
+def _has_rope(config: Dict) -> bool:
+    kind = config.get("position_embedding_type", "rope")
+    if kind not in POSITIONS:
+        raise ValueError(f"{config['name']}.json: position_embedding_type "
+                         f"{kind!r}; the harness knows {sorted(POSITIONS)}")
+    return POSITIONS[kind]
+
+
+def _activation(v, config: Dict):
+    if v not in ACTIVATIONS:
+        raise ValueError(f"{config['name']}.json: hidden_act {v!r}; the "
+                         f"harness knows {sorted(ACTIVATIONS)}")
+    return ACTIVATIONS[v]
+
+
+def _same(v, config: Dict):
+    return v
+
+
+# file key -> (the program's ``ModelConfig`` attribute, the file's value as
+# the program states it). Each key is checked where the file states it or,
+# where ``DEFAULTS`` gives one, at the value that its absence means.
+CHECKS: Dict[str, Tuple[str, Callable]] = {
+    "hidden_size": ("d_model", _same),
+    "num_hidden_layers": ("n_layers", _same),
+    "num_attention_heads": ("n_heads", _same),
+    "num_key_value_heads": ("n_kv_heads", _same),
+    "head_dim": ("head_dim", _same),
+    "vocab_size": ("vocab_size", _same),
+    "tie_word_embeddings": ("tie_embeddings", _same),
+    "position_embedding_type": ("use_rope", lambda v, c: _has_rope(c)),
+    "rope_theta": ("rope_theta", _same),
+    "num_local_experts": ("n_experts", _same),
+    "num_experts_per_tok": ("top_k", _same),
+    "hidden_act": ("activation", _activation),
+    "normalization_function": ("norm", _same),
+    "sliding_window": ("sliding_window", lambda v, c: v or 0),
+    "layer_types": ("layer_pattern", lambda v, c: _pattern(c)),
+    "mamba_d_state": ("ssm_state", _same),
+    "mamba_d_head": ("ssm_head_dim", _same),
+    "mamba_n_heads": ("ssm_nheads", _same),
+    "mamba_n_groups": ("ssm_ngroups", _same),
+    "mamba_d_conv": ("ssm_conv", _same),
+    "mamba_expand": ("ssm_expand", _same),
+    "mamba_chunk_size": ("ssm_chunk", _same),
+}
+# what a file that leaves a key out states by that (Hugging Face's
+# defaults); ``None``: nothing, as the key it follows from is left out too
+DEFAULTS: Dict[str, Callable] = {
+    "head_dim": lambda c: (c["hidden_size"] // c["num_attention_heads"]
+                           if "num_attention_heads" in c else None),
+    "position_embedding_type": lambda c: "rope",
+    "num_local_experts": lambda c: 0,
+    "num_experts_per_tok": lambda c: 0,
+    "normalization_function": lambda c: "rmsnorm",
+    "sliding_window": lambda c: 0,
+    "layer_types": lambda c: ["attention"],
+}
+# a file with Mamba2 layers states every size of them
+MAMBA_KEYS = tuple(k for k in CHECKS if k.startswith("mamba_"))
+
+
+def wanted(config: Dict) -> Dict[str, Tuple[str, object]]:
+    """Every program attribute the file fixes: attribute -> (file key, the
+    value the program has to hold). ``intermediate_size`` is the width of
+    one expert where the file has experts, else the MLP's; the file's
+    ``program.checks`` (``{attribute: file key}``) adds attributes that a
+    configuration brings with it, each taken as the file states it."""
+    if "ssm" in layer_types(config):
+        missing = [k for k in MAMBA_KEYS if k not in config]
+        if missing:
+            raise ValueError(f"{config['name']}.json has Mamba2 layers and "
+                             f"does not state {missing}")
+    out = {}
+    for key, (attr, how) in CHECKS.items():
+        v = config[key] if key in config else DEFAULTS.get(
+            key, lambda c: None)(config)
+        if key in config or v is not None:
+            out[attr] = (key, how(v, config))
+    if "intermediate_size" in config:
+        ff = "moe_d_ff" if config.get("num_local_experts") else "d_ff"
+        out[ff] = ("intermediate_size", config["intermediate_size"])
+    if not _has_rope(config):  # NoPE: the theta the file states is unused
+        out.pop("rope_theta", None)
+    for attr, key in config["program"].get("checks", {}).items():
+        if key not in config:
+            raise ValueError(f"{config['name']}.json: program.checks names "
+                             f"{key!r} for {attr!r}, and the file has no {key!r}")
+        out[attr] = (key, config[key])
+    return out
 
 
 def program_config(config: Dict):
     """The program's ``ModelConfig`` for a configuration file: the registry
     entry ``program.arch`` with ``program.set`` applied. Every size the file
-    states is checked against it, so the file stays the configuration as
-    run."""
+    states is checked against it (``wanted``), so the file stays the
+    configuration as run; a checked attribute that the program's config
+    does not have is refused by name."""
     import dataclasses
 
     from repro.configs import get_config
     from repro.configs.base import ElasticConfig
 
     prog = config["program"]
-    kw = dict(prog.get("set", {}))
+    # JSON has lists where the program's (hashable) config holds tuples
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in prog.get("set", {}).items()}
     sv = config["serving"]
     kw["elastic"] = ElasticConfig(
         width_fractions=tuple(sv["width_fractions"]),
@@ -106,32 +252,35 @@ def program_config(config: Dict):
     kw["param_dtype"] = sv["param_dtype"]
     kw["dtype"] = sv["dtype"]
     cfg = dataclasses.replace(get_config(prog["arch"]), **kw)
-    moe = config.get("num_local_experts", 0)
-    want = {
-        "d_model": config["hidden_size"],
-        "n_layers": config["num_hidden_layers"],
-        "n_heads": config["num_attention_heads"],
-        "n_kv_heads": config["num_key_value_heads"],
-        "head_dim": config["hidden_size"] // config["num_attention_heads"],
-        "vocab_size": config["vocab_size"],
-        "tie_embeddings": config["tie_word_embeddings"],
-        "rope_theta": config["rope_theta"],
-        "n_experts": moe,
-        "top_k": config.get("num_experts_per_tok", 0),
-        ("moe_d_ff" if moe else "d_ff"): config["intermediate_size"],
-        "activation": {"silu": "swiglu"}[config["hidden_act"]],
-        "norm": "rmsnorm",
-        "sliding_window": config.get("sliding_window") or 0,
-        "period": 1,
-    }
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items()
-           if getattr(cfg, k) != v}
+    want = wanted(config)
+    missing = sorted(a for a in want if not hasattr(cfg, a))
+    if missing:
+        raise ValueError(f"{config['name']}.json checks {missing}, which the "
+                         f"program's config {cfg.name} does not have")
+    bad = {f"{key} -> {attr}": (getattr(cfg, attr), v)
+           for attr, (key, v) in want.items() if getattr(cfg, attr) != v}
     if cfg.padded_vocab() != sv["padded_vocab"]:  # the weights' drawn shape
         bad["padded_vocab"] = (cfg.padded_vocab(), sv["padded_vocab"])
     if bad:
         raise ValueError(f"program config {cfg.name} differs from "
                          f"{config['name']}.json (program, file): {bad}")
     return cfg
+
+
+# the leaves that project a block's output into the residual stream:
+# attention's and every MLP's or expert's ``wo`` (a shared expert's too,
+# wherever the program nests it) and a Mamba2 mixer's ``out_proj``
+OUTPUT_PROJECTIONS = ("wo", "out_proj")
+
+
+def head_dim(config: Dict):
+    """The attention head size: the file's ``head_dim`` where it states
+    one, else ``hidden_size / num_attention_heads`` (``None`` without
+    attention heads)."""
+    if "head_dim" in config:
+        return config["head_dim"]
+    heads = config.get("num_attention_heads")
+    return config["hidden_size"] // heads if heads else None
 
 
 def fold_multipliers(params, config: Dict):
@@ -143,14 +292,17 @@ def fold_multipliers(params, config: Dict):
     attention scores, ``residual_multiplier`` scales every block's output
     before it joins the residual stream, and ``logits_scaling`` divides
     the logits. Each is linear, so it goes exactly into a weight: the
-    embedding, the query projection, the attention and MLP/expert output
-    projections, and the final and exit norms' scales (the unembedding
-    when it is not tied). With every multiplier at 1 nothing changes."""
+    embedding, each attention layer's query projection, every block's
+    output projection (``OUTPUT_PROJECTIONS``: attention, Mamba2 mixer,
+    MLP, experts, shared expert), each scaled once, and the final and exit
+    norms' scales (the unembedding when it is not tied). With every
+    multiplier at 1 nothing changes."""
     import math
 
-    hd = config["hidden_size"] // config["num_attention_heads"]
+    hd = head_dim(config)
+    att = (float(config.get("attention_multiplier", 1 / math.sqrt(hd)))
+           * math.sqrt(hd) if hd else 1.0)
     emb = float(config.get("embedding_multiplier", 1.0))
-    att = float(config.get("attention_multiplier", 1 / math.sqrt(hd))) * math.sqrt(hd)
     res = float(config.get("residual_multiplier", 1.0))
     logit = float(config.get("logits_scaling", 1.0))
     if (emb, att, res, logit) == (1.0, 1.0, 1.0, 1.0):
@@ -158,6 +310,11 @@ def fold_multipliers(params, config: Dict):
 
     def scaled(w, f):
         return (w.astype("float32") * f).astype(w.dtype)
+
+    def outputs_scaled(tree):
+        return {k: scaled(v, res) if k in OUTPUT_PROJECTIONS
+                else outputs_scaled(v) if isinstance(v, dict) else v
+                for k, v in tree.items()}
 
     p = dict(params)
     p["embed"] = scaled(p["embed"], emb)
@@ -171,12 +328,9 @@ def fold_multipliers(params, config: Dict):
                            for g, n in p["exit_norms"].items()}
     stack = {}
     for pos, lp in p["stack"].items():
-        lp = dict(lp)
-        lp["attn"] = {**lp["attn"], "wq": scaled(lp["attn"]["wq"], att),
-                      "wo": scaled(lp["attn"]["wo"], res)}
-        for ff in ("moe", "mlp"):
-            if ff in lp:
-                lp[ff] = {**lp[ff], "wo": scaled(lp[ff]["wo"], res)}
+        lp = outputs_scaled(lp)
+        if "attn" in lp:
+            lp["attn"] = {**lp["attn"], "wq": scaled(lp["attn"]["wq"], att)}
         stack[pos] = lp
     p["stack"] = stack
     return p
@@ -204,7 +358,7 @@ def make_weights(config: Dict, cfg, key, mesh=None):
 
 
 def mode_table(config: Dict, traffic: Dict):
-    """(depth, width) of every entry of the mix's mode schedule."""
+    """(depth in layers, width) of every entry of the mix's mode schedule."""
     return [(depth_of(config, m["depth"]), float(m["width"]))
             for m in traffic["modes"]["mix"]]
 

@@ -134,7 +134,8 @@ class Served:
         self.conf, self.mix = conf, mix = cell.config, cell.traffic
         self.ref = S.reference_module(conf)
         cfg = S.program_config(conf)
-        self.modes = [MorphMode(d, w) for d, w in S.mode_table(conf, mix)]
+        self.modes = [MorphMode(S.engine_depth(conf, d), w)
+                      for d, w in S.mode_table(conf, mix)]
         self.traffic = Traffic(mix, seed=seed, seconds=seconds,
                                vocab=conf["vocab_size"])
         self.peaks = peaks_for(dev.device_kind) if require_accelerator else {}
